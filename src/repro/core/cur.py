@@ -18,6 +18,10 @@ def rank_for(m: int, n: int, r_max: int = 256) -> int:
     if r_star < 1:
         return 1
     r = 2 ** int(math.floor(math.log2(r_star)))
+    # r* is the root of mr + r^2 + rn = mn: an exact power of two lands
+    # on equality, which Eq. 2's strict inequality excludes
+    while r > 1 and m * r + r * r + r * n >= m * n:
+        r //= 2
     return min(r, r_max)
 
 

@@ -37,16 +37,12 @@ from __future__ import annotations
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import (
+    AbstractMesh, AxisType, Mesh, NamedSharding, PartitionSpec as P)
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.optim.adamw import (
     STATE_FULL_KEYS, STATE_SCALE_KEYS, state_spec_from_param)
-
-try:  # jax >= 0.4.31
-    from jax.sharding import AbstractMesh
-except ImportError:  # pragma: no cover
-    AbstractMesh = None
 
 # CUR dict leaf keys (healing and folded serving forms)
 _CUR_FULL = ("C", "CU")          # inherit input-dim sharding
@@ -66,13 +62,8 @@ _ROW_PARALLEL = frozenset(("wo", "w_out"))
 
 
 def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Version-portable AbstractMesh((16, 16), ("data", "model"))."""
-    if AbstractMesh is None:  # pragma: no cover
-        raise RuntimeError("jax.sharding.AbstractMesh unavailable")
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """AbstractMesh((16, 16), ("data", "model"))."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 # ---------------------------------------------------------------------------
@@ -314,7 +305,7 @@ def cache_pspecs(cache, cfg: ModelConfig, shape: ShapeConfig, mesh):
 
 def _paged_leaf_spec(path, leaf, cfg: ModelConfig, mesh,
                      kernel: bool = False):
-    """Paged-pool leaves. Pools (L, n_blocks, bs, K, r): blocks are shared
+    """Paged-pool leaves. Pools (L, n_blocks, K, bs, r): blocks are shared
     by all sequences, so there is no batch axis — one axis shards over
     'model' by first-divisible priority (kv-heads, then feature/rank,
     then the block pool). CUR-KV projections and block tables replicate
@@ -322,14 +313,14 @@ def _paged_leaf_spec(path, leaf, cfg: ModelConfig, mesh,
 
     ``kernel=True`` (the ``paged_pallas`` decode backend, resolved by the
     attention registry's ``REPRO_PAGED_KERNEL`` gate): the
-    kernel grids over (slot, kv-head, block) and holds a whole
-    ``(block_size, r)`` tile per step, so kv-heads is the ONLY pool axis
-    it can shard — the rank/block-pool fallbacks would split in-kernel
-    tiles. Non-divisible kv-heads replicate instead of falling back."""
+    kernel grids over (slot, block) and holds whole ``(block_size, r)``
+    tiles per kv-head, so kv-heads is the ONLY pool axis it can shard —
+    the rank/block-pool fallbacks would split in-kernel tiles.
+    Non-divisible kv-heads replicate instead of falling back."""
     shape = tuple(leaf.shape)
     key = path[-1] if path and isinstance(path[-1], str) else None
-    if key in ("k", "v") and len(shape) == 5:   # (L, nb, bs, K, r)
-        cands = [[None, None, None, "model", None]]
+    if key in ("k", "v") and len(shape) == 5:   # (L, nb, K, bs, r)
+        cands = [[None, None, "model", None, None]]
         if not kernel:
             cands += [[None, None, None, None, "model"],
                       [None, "model", None, None, None]]
@@ -370,7 +361,15 @@ def paged_decode_pspecs(cfg: ModelConfig, batch: int, max_blocks: int, mesh,
 
 def to_named(specs, mesh):
     """PartitionSpec pytree -> NamedSharding pytree (None -> replicated).
-    The result feeds ``jax.jit`` in/out_shardings and ``jax.device_put``."""
+    The result feeds ``jax.jit`` in/out_shardings and ``jax.device_put``.
+
+    The specs place inputs and GSPMD propagates the rest, so the
+    shardings sit on an Auto-typed view of ``mesh``: ``jax.make_mesh``
+    returns Explicit axes, under which every gather and matmul whose
+    output sharding is ambiguous would have to name it."""
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
     def conv(s):
         if s is None:
             return NamedSharding(mesh, P())

@@ -14,12 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -118,11 +113,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         _kernel, scale=scale, bq=bq, bk=bk, nk=nk,
         causal=causal, window=window, kv_len=S)
 
-    scratch = ([_VMEM((bq, 1), jnp.float32),
-                _VMEM((bq, 1), jnp.float32),
-                _VMEM((bq, d), jnp.float32)] if _VMEM is not None else
-               [pl.MemorySpace.ANY] * 3)  # pragma: no cover
-
     out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
@@ -135,7 +125,10 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, d), q.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out[:, :, :S, :] if Sq != S else out

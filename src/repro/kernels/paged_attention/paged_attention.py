@@ -2,14 +2,17 @@
 softmax, rank-space CUR-KV.
 
 One query token per slot attends to its paged KV history *in place*: the
-grid is (B, K, maxb) with the per-sequence block index innermost, and a
+grid is (B, maxb) with the per-sequence block index innermost, and a
 scalar-prefetched block table drives the K/V BlockSpec index maps — each
-grid step DMAs exactly one ``(block_size, r)`` pool block into VMEM, so
-the full ``(B, maxb*bs, K, r)`` gather (and, in CUR-KV mode, the fp32
-``(.., head_dim)`` reconstruction) that the XLA path materializes in HBM
-never exists. Per-(slot, kv-head) running (max, sum, acc) f32 scratch
-implements the online softmax across blocks, exactly like
-``flash_attention``'s KV-tile loop.
+grid step DMAs exactly one ``(K, block_size, r)`` pool block (every
+kv-head of one block) into VMEM, so the full ``(B, maxb*bs, K, r)``
+gather (and, in CUR-KV mode, the fp32 ``(.., head_dim)`` reconstruction)
+that the XLA path materializes in HBM never exists. Pools are laid out
+``(n_blocks, K, bs, r)`` so a block's last two dims ``(bs, r)`` meet the
+TPU tiling rule (multiples of (8, 128), or the full dims) for any rank.
+Per-(slot, kv-head) running (max, sum, acc) f32 scratch implements the
+online softmax across blocks, exactly like ``flash_attention``'s KV-tile
+loop; all kv-heads of a block are scored in one batched matmul.
 
 CUR-KV attention happens natively in rank space: the caller folds the key
 link matrix into the query (``q̃ = scale * q @ Ukᵀ``, see ``ref.fold_q``)
@@ -35,13 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -49,7 +46,7 @@ NEG_INF = -1e30
 def _kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *, bs, nb, window, span=1):
     b = pl.program_id(0)
-    j = pl.program_id(2)          # per-sequence block index (innermost)
+    j = pl.program_id(1)          # per-sequence block index (innermost)
 
     @pl.when(j == 0)
     def _init():
@@ -67,19 +64,19 @@ def _kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _update():
-        q = q_ref[0, 0]                          # (G, r), pre-scaled/folded
-        k = k_ref[0, :, 0]                       # (bs, r)
-        v = v_ref[0, :, 0]
+        q = q_ref[0]                             # (K, G, r), pre-scaled
+        k = k_ref[0]                             # (K, bs, r)
+        v = v_ref[0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (G, bs)
-        idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)  # (K, G, bs)
+        idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         if span > 1:
             # speculative-verify layout: G = span * group, row g is query
             # position ctx + g // group (same per-row mask as span
             # sequential decode steps; one DMA'd KV tile serves them all)
-            goff = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                    // (q.shape[0] // span))
+            goff = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                    // (q.shape[1] // span))
             qpos = ctx + goff
         else:
             qpos = ctx
@@ -93,7 +90,7 @@ def _kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -101,57 +98,47 @@ def _kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
     def _finish():
         # l == 0 (no live block anywhere, e.g. an inactive slot with an
         # all-unassigned table row): acc is zero -> exact zero output
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, table, ctx_len, *, window: int = 0,
                     q_span: int = 1, interpret: bool = False):
     """q (B, K, G, r) folded/pre-scaled queries; k/v_pool
-    (n_blocks, bs, K, r); table (B, maxb) int32 (-1 = unassigned);
+    (n_blocks, K, bs, r); table (B, maxb) int32 (-1 = unassigned);
     ctx_len (B,) newest-token index. Returns (B, K, G, r) rank-space
     attention outputs (apply ``Uv`` outside for CUR-KV pools).
 
     ``q_span = S > 1``: multi-position verify — ``G`` must be
     ``S * group`` with row ``g`` the query at position ``ctx + g //
     group`` (see ``ref.paged_attention_ref``); each pool block is still
-    DMA'd exactly once per (slot, kv-head)."""
+    DMA'd exactly once per slot."""
     B, K, G, r = q.shape
-    nb_pool, bs, Kp, rp = k_pool.shape
+    nb_pool, Kp, bs, rp = k_pool.shape
     if (Kp, rp) != (K, r) or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pool/query mismatch: q {q.shape}, k_pool {k_pool.shape}, "
             f"v_pool {v_pool.shape}")
     if q_span > 1 and G % q_span != 0:
         raise ValueError(f"q_span {q_span} must divide query rows {G}")
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("paged_attention needs pallas.tpu "
-                           "(PrefetchScalarGridSpec)")
     maxb = table.shape[1]
     kernel = functools.partial(_kernel, bs=bs, nb=maxb, window=window,
                                span=q_span)
-
+    # the block table IS the index map: unassigned entries clamp to
+    # block 0 (their tile is DMA'd but pl.when-skipped)
+    kv_spec = pl.BlockSpec(
+        (1, K, bs, r),
+        lambda b, j, tbl, ctx: (jnp.maximum(tbl[b, j], 0), 0, 0, 0))
+    q_spec = pl.BlockSpec((1, K, G, r), lambda b, j, tbl, ctx: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, K, maxb),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, r),
-                         lambda b, k, j, tbl, ctx: (b, k, 0, 0)),
-            # the block table IS the index map: unassigned entries clamp
-            # to block 0 (their tile is DMA'd but pl.when-skipped)
-            pl.BlockSpec((1, bs, 1, r),
-                         lambda b, k, j, tbl, ctx:
-                         (jnp.maximum(tbl[b, j], 0), 0, k, 0)),
-            pl.BlockSpec((1, bs, 1, r),
-                         lambda b, k, j, tbl, ctx:
-                         (jnp.maximum(tbl[b, j], 0), 0, k, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, r),
-                               lambda b, k, j, tbl, ctx: (b, k, 0, 0)),
+        grid=(B, maxb),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            _VMEM((G, 1), jnp.float32),
-            _VMEM((G, 1), jnp.float32),
-            _VMEM((G, r), jnp.float32),
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, 1), jnp.float32),
+            pltpu.VMEM((K, G, r), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -159,5 +146,6 @@ def paged_attention(q, k_pool, v_pool, table, ctx_len, *, window: int = 0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, r), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(table.astype(jnp.int32), ctx_len.astype(jnp.int32), q,
       k_pool, v_pool)

@@ -41,7 +41,7 @@ def unfold_o(o: jnp.ndarray, uv) -> jnp.ndarray:
 def paged_attention_ref(q, k_pool, v_pool, table, ctx_len, *,
                         window: int = 0, q_span: int = 1):
     """Gather-based oracle. q (B, K, G', r) folded/pre-scaled; pools
-    (n_blocks, bs, K, r); table (B, maxb); ctx_len (B,). -> (B, K, G', r).
+    (n_blocks, K, bs, r); table (B, maxb); ctx_len (B,). -> (B, K, G', r).
 
     ``q_span = S > 1`` is the speculative-verify layout: ``G' = S * G``
     rows per kv-head, row ``g`` holding query position ``ctx + g // G``
@@ -52,11 +52,15 @@ def paged_attention_ref(q, k_pool, v_pool, table, ctx_len, *,
     verifying k+1 draft positions costs ONE table-width gather instead
     of k+1."""
     B, maxb = table.shape
-    bs = k_pool.shape[1]
+    K, bs, r = k_pool.shape[1:]
     L = maxb * bs
     Gq = q.shape[2]
-    ck = k_pool[jnp.maximum(table, 0)].reshape(B, L, *k_pool.shape[2:])
-    cv = v_pool[jnp.maximum(table, 0)].reshape(B, L, *v_pool.shape[2:])
+
+    def gather(pool):                       # (B, L, K, r) table view
+        g = jnp.swapaxes(pool[jnp.maximum(table, 0)], 2, 3)
+        return g.reshape(B, L, K, r)
+
+    ck, cv = gather(k_pool), gather(v_pool)
     s = jnp.einsum("bkgr,btkr->bkgt", q.astype(jnp.float32),
                    ck.astype(jnp.float32))
     idx = jnp.arange(L, dtype=jnp.int32)

@@ -31,6 +31,7 @@ from repro.core import calibrate, compress_model
 from repro.core.compress import rank_key
 from repro.data.tokens import DataConfig, SyntheticLM
 from repro.dist.checkpoint import CheckpointManager, save_tree_template
+from repro.launch import compile_cache
 from repro.models import init_params
 from repro.plan import CompressionPlan, config_hash, plan_for_model
 from repro.serve.engine import generate
@@ -254,6 +255,7 @@ def cure(args) -> dict:
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=list(ARCHS))
     ap.add_argument("--smoke", action="store_true")

@@ -37,6 +37,7 @@ from repro.configs import ARCHS, get_config, get_smoke
 from repro.configs.base import CURConfig
 from repro.core import calibrate, compress_model
 from repro.data.tokens import DataConfig, SyntheticLM
+from repro.launch import compile_cache
 from repro.models import init_params
 from repro.serve.engine import generate
 from repro.serving import PagedConfig, Server
@@ -89,6 +90,9 @@ def run_continuous(server: Server, workload, *, temperature: float = 0.0,
 
 
 def main(argv=None):
+    """Parse ``argv`` and serve; the paged path returns ``(stats,
+    finished)``, the server's stats and its finished requests by id."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=list(ARCHS))
     ap.add_argument("--smoke", action="store_true")
@@ -393,7 +397,7 @@ def main(argv=None):
             tracer=tracer)
         for kind, path in written.items():
             print(f"  obs {kind} -> {path}")
-    return stats
+    return stats, finished
 
 
 if __name__ == "__main__":

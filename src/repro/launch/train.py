@@ -17,12 +17,14 @@ from repro.configs.base import OptimizerConfig, TrainConfig
 from repro.data.tokens import DataConfig, SyntheticLM
 from repro.dist.checkpoint import CheckpointManager
 from repro.dist.compression import init_residuals
+from repro.launch import compile_cache
 from repro.models import init_params
 from repro.optim.adamw import AdamW
 from repro.train.train_loop import StragglerWatchdog, train
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=list(ARCHS))
     ap.add_argument("--smoke", action="store_true",

@@ -24,10 +24,7 @@ from repro.models.moe import moe_forward
 
 Params = Dict[str, Any]
 
-try:
-    from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
-except ImportError:  # pragma: no cover
-    from jax._src.ad_checkpoint import checkpoint_name as _checkpoint_name
+from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,9 @@
 """Scoped ``jax.profiler`` capture + device memory snapshots.
 
 The XLA profiler is process-global and heavyweight, so this wrapper
-keeps it strictly opt-in (``--prof``) and failure-tolerant: platforms
-or builds without profiler support degrade to a no-op instead of
-killing the serve loop. Captures are keyed to obs spans by emitting a
+keeps it strictly opt-in (``--prof``). A capture that was asked for and
+cannot start raises: a run that silently drops its trace would be read
+as a run without one. Captures are keyed to obs spans by emitting a
 matching instant event on the tracer, so the Perfetto timeline and the
 XLA trace directory line up by name.
 """
@@ -23,10 +23,7 @@ def device_memory_snapshot() -> dict:
     report any, e.g. CPU)."""
     out = {}
     for d in jax.local_devices():
-        try:
-            stats = d.memory_stats()
-        except Exception:
-            stats = None
+        stats = d.memory_stats()
         if stats:
             out[str(d)] = {k: int(v) for k, v in stats.items()
                            if isinstance(v, (int, float))}
@@ -47,18 +44,14 @@ class JaxProfiler:
         self.out_dir = out_dir
         self.tracer = tracer
         self.active = False
-        self.available = out_dir is not None
 
     def start(self) -> bool:
-        if not self.available or self.active:
+        if self.out_dir is None or self.active:
             return False
-        try:
-            os.makedirs(self.out_dir, exist_ok=True)
-            jax.profiler.start_trace(self.out_dir)
-            self.active = True
-        except Exception:
-            self.available = False
-        return self.active
+        os.makedirs(self.out_dir, exist_ok=True)
+        jax.profiler.start_trace(self.out_dir)
+        self.active = True
+        return True
 
     def stop(self) -> None:
         if not self.active:

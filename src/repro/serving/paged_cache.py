@@ -258,16 +258,18 @@ def serving_window(cfg: ModelConfig) -> int:
 
 
 def init_paged_cache(cfg: ModelConfig, pc: PagedConfig) -> dict:
-    """Pool pytree: k/v (L, n_blocks, block_size, K, r) plus, in CUR-KV
+    """Pool pytree: k/v (L, n_blocks, K, block_size, r) plus, in CUR-KV
     mode, per-layer column indices and link matrices (identity-truncation
-    placeholders until :func:`set_kv_projections` calibrates them)."""
+    placeholders until :func:`set_kv_projections` calibrates them).
+    Each block's trailing ``(block_size, r)`` dims are what the paged
+    kernel DMAs per kv-head, so they are the TPU-tiled pair."""
     L = _attn_layers(cfg)
     K, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     r = pc.rank(hd)
     dtype = jnp.dtype(cfg.dtype)
     cache = {
-        "k": jnp.zeros((L, pc.n_blocks, pc.block_size, K, r), dtype),
-        "v": jnp.zeros((L, pc.n_blocks, pc.block_size, K, r), dtype),
+        "k": jnp.zeros((L, pc.n_blocks, K, pc.block_size, r), dtype),
+        "v": jnp.zeros((L, pc.n_blocks, K, pc.block_size, r), dtype),
     }
     if pc.cur_kv:
         eye = jnp.broadcast_to(jnp.eye(r, hd, dtype=jnp.float32),
@@ -338,7 +340,7 @@ def write_prompt(pool: jnp.ndarray, x: jnp.ndarray, table: jnp.ndarray,
                  lengths: jnp.ndarray, block_size: int) -> jnp.ndarray:
     """Scatter a padded prompt's per-token rows into one layer's pool.
 
-    pool (n_blocks, bs, K, r); x (B, S, K, r); table (B, maxb) int32 with
+    pool (n_blocks, K, bs, r); x (B, S, K, r); table (B, maxb) int32 with
     -1 padding; lengths (B,). Rows past a sequence's length (and rows of
     inactive table entries) scatter out of bounds and are dropped.
     NB: the drop sentinel must be ``n_blocks`` (one past the end), never
@@ -352,7 +354,7 @@ def write_prompt(pool: jnp.ndarray, x: jnp.ndarray, table: jnp.ndarray,
     valid = (t[None, :] < lengths[:, None]) & (blk >= 0)
     blk = jnp.where(valid, blk, n_blocks)
     off = jnp.broadcast_to(t[None] % block_size, (B, S))
-    return pool.at[blk, off].set(x, mode="drop")
+    return pool.at[blk, :, off].set(x, mode="drop")
 
 
 def write_token(pool: jnp.ndarray, x: jnp.ndarray, table: jnp.ndarray,
@@ -364,7 +366,7 @@ def write_token(pool: jnp.ndarray, x: jnp.ndarray, table: jnp.ndarray,
                               axis=1)[:, 0]
     blk = jnp.where(active & (blk >= 0), blk, pool.shape[0])
     off = pos % block_size
-    return pool.at[blk, off].set(x, mode="drop")
+    return pool.at[blk, :, off].set(x, mode="drop")
 
 
 def write_span(pool: jnp.ndarray, x: jnp.ndarray, table: jnp.ndarray,
@@ -385,7 +387,7 @@ def write_span(pool: jnp.ndarray, x: jnp.ndarray, table: jnp.ndarray,
     valid = active[:, None] & (blk >= 0) & (bi < maxb)
     blk = jnp.where(valid, blk, n_blocks)
     off = t % block_size
-    return pool.at[blk, off].set(x, mode="drop")
+    return pool.at[blk, :, off].set(x, mode="drop")
 
 
 def copy_cache_blocks(cache: dict, src: jnp.ndarray,
@@ -397,7 +399,7 @@ def copy_cache_blocks(cache: dict, src: jnp.ndarray,
     (drop sentinel); their ``src`` is clamped for the gather."""
     new = dict(cache)
     for name in ("k", "v"):
-        pool = cache[name]                     # (L, nb, bs, K, r)
+        pool = cache[name]                     # (L, nb, K, bs, r)
         nb = pool.shape[1]
         data = jnp.take(pool, jnp.clip(src, 0, nb - 1), axis=1)
         new[name] = pool.at[:, dst].set(data, mode="drop")
@@ -408,6 +410,6 @@ def gather_kv(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """Gather every sequence's cache view: (B, maxb*bs, K, r). Unassigned
     table entries read block 0 — callers mask by context length."""
     B, maxb = table.shape
-    g = pool[jnp.maximum(table, 0)]            # (B, maxb, bs, K, r)
-    nb, bs = g.shape[1], g.shape[2]
-    return g.reshape(B, nb * bs, *g.shape[3:])
+    g = pool[jnp.maximum(table, 0)]            # (B, maxb, K, bs, r)
+    g = jnp.swapaxes(g, 2, 3)                  # (B, maxb, bs, K, r)
+    return g.reshape(B, maxb * g.shape[2], *g.shape[3:])

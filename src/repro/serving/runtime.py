@@ -53,7 +53,7 @@ def _paged_attn(qg, k_pool, v_pool, table, ctx_len, uk, uv, scale,
                 window: int, kernel=None, q_span: int = 1):
     """Rank-space paged attention for one layer's single-token queries.
 
-    qg (B, K, G, hd) grouped queries; pools (n_blocks, bs, K, r).
+    qg (B, K, G, hd) grouped queries; pools (n_blocks, K, bs, r).
     Returns (B, K, G, hd) — rank-space scores/values with the Uk/Uv
     folds, resolved through the registry's ``paged_decode`` variant
     (Pallas block-table kernel when gated on, else the gather-based XLA

@@ -1,19 +1,8 @@
 import os
-import sys
 
 # Tests must see ONE device (the dry-run sets its own 512-device flag in a
 # subprocess); keep CPU math deterministic.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# The property tests want hypothesis (requirements.txt); containers without
-# it fall back to a seeded-sweep stub so those modules still collect.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_stub
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
 
 import jax
 import jax.numpy as jnp

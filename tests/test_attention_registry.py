@@ -81,14 +81,14 @@ def test_paged_decode_backend_parity():
     B, K, G, r, bs, maxb = 2, 2, 2, 16, 4, 4
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (B, K, G, r))
-    kp = jax.random.normal(ks[1], (B * maxb, bs, K, r))
-    vp = jax.random.normal(ks[2], (B * maxb, bs, K, r))
+    kp = jax.random.normal(ks[1], (B * maxb, K, bs, r))
+    vp = jax.random.normal(ks[2], (B * maxb, K, bs, r))
     table = jnp.arange(B * maxb, dtype=jnp.int32).reshape(B, maxb)
     ctx = jnp.asarray([5, 13], jnp.int32)
     # dense oracle over the gathered-contiguous layout
     L = maxb * bs
-    kd = kp.reshape(B, L, K, r)
-    vd = vp.reshape(B, L, K, r)
+    kd = kp.reshape(B, maxb, K, bs, r).swapaxes(2, 3).reshape(B, L, K, r)
+    vd = vp.reshape(B, maxb, K, bs, r).swapaxes(2, 3).reshape(B, L, K, r)
     # no scale: paged backends take pre-scaled (folded) queries
     logits = jnp.einsum("bkgr,blkr->bkgl", q, kd)
     mask = jnp.arange(L)[None, :] <= ctx[:, None]

@@ -389,7 +389,7 @@ def test_serve_cli_obs_smoke(tmp_path):
     Chrome trace + metrics artifacts (the CI tier-1 smoke)."""
     from repro.launch.serve import main as serve_main
     out = str(tmp_path / "obs")
-    stats = serve_main([
+    stats, _ = serve_main([
         "--arch", "olmo-1b", "--smoke", "--n-requests", "4",
         "--new-tokens", "4", "--max-concurrency", "2",
         "--obs", "--trace", "--obs-out", out])

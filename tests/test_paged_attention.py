@@ -19,8 +19,8 @@ def _case(B, K, G, r, nb, bs, maxb, *, seed=0, dtype=jnp.float32,
     rng = np.random.RandomState(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, K, G, r), jnp.float32).astype(dtype)
-    kp = jax.random.normal(ks[1], (nb, bs, K, r), jnp.float32).astype(dtype)
-    vp = jax.random.normal(ks[2], (nb, bs, K, r), jnp.float32).astype(dtype)
+    kp = jax.random.normal(ks[1], (nb, K, bs, r), jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[2], (nb, K, bs, r), jnp.float32).astype(dtype)
     ctx = np.array([rng.randint(0, maxb * bs) for _ in range(B)], np.int32)
     table = np.full((B, maxb), -1, np.int32)
     free = list(rng.permutation(nb))
@@ -78,8 +78,8 @@ def test_kernel_matches_dense_oracle():
     y = paged_attention_op(q, kp, vp, table, ctx)
     # dense oracle over the gathered-contiguous layout
     L = maxb * bs
-    kd = kp[table].reshape(B, L, K, r)
-    vd = vp[table].reshape(B, L, K, r)
+    kd = kp[table].swapaxes(2, 3).reshape(B, L, K, r)
+    vd = vp[table].swapaxes(2, 3).reshape(B, L, K, r)
     s = jnp.einsum("bkgr,btkr->bkgt", q, kd).astype(jnp.float32)
     mask = jnp.arange(L)[None] <= np.asarray(ctx)[:, None]
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
@@ -96,8 +96,8 @@ def test_rank_space_fold_equals_reconstruct(r):
     hd, B, K, G, bs, maxb, nb = 16, 2, 2, 2, 4, 3, 8
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
     q = jax.random.normal(ks[0], (B, K, G, hd))
-    kp = jax.random.normal(ks[1], (nb, bs, K, r))
-    vp = jax.random.normal(ks[2], (nb, bs, K, r))
+    kp = jax.random.normal(ks[1], (nb, K, bs, r))
+    vp = jax.random.normal(ks[2], (nb, K, bs, r))
     uk = jax.random.normal(ks[3], (r, hd))
     uv = jax.random.normal(ks[4], (r, hd))
     table = jnp.arange(B * maxb, dtype=jnp.int32).reshape(B, maxb)
@@ -108,8 +108,8 @@ def test_rank_space_fold_equals_reconstruct(r):
                                      table, ctx), uv)
     # reconstruct-then-attend oracle (the old decode formulation)
     L = maxb * bs
-    kh = (kp[table].reshape(B, L, K, r) @ uk)          # (B, L, K, hd)
-    vh = (vp[table].reshape(B, L, K, r) @ uv)
+    kh = (kp[table].swapaxes(2, 3).reshape(B, L, K, r) @ uk)          # (B, L, K, hd)
+    vh = (vp[table].swapaxes(2, 3).reshape(B, L, K, r) @ uv)
     s = jnp.einsum("bkgd,btkd->bkgt", q, kh).astype(jnp.float32) * scale
     mask = jnp.arange(L)[None] <= np.asarray(ctx)[:, None]
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
@@ -178,8 +178,8 @@ def test_q_span_must_divide_groups():
 
 def test_kernel_shape_mismatch_raises():
     q = jnp.zeros((2, 2, 2, 8))
-    kp = jnp.zeros((4, 4, 2, 8))
-    vp_bad = jnp.zeros((4, 4, 2, 4))
+    kp = jnp.zeros((4, 2, 4, 8))
+    vp_bad = jnp.zeros((4, 2, 4, 4))
     table = jnp.zeros((2, 2), jnp.int32)
     ctx = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError, match="mismatch"):

@@ -210,8 +210,8 @@ def test_decode_fold_matches_old_reconstruct_path(olmo, rank_div):
     key = jax.random.PRNGKey(2)
     k1, k2, k3 = jax.random.split(key, 3)
     B, K, G, nb, bs, maxb = 3, cfg.n_kv_heads, 1, 12, 4, 3
-    pool_k = jax.random.normal(k1, (nb, bs, K, r))
-    pool_v = jax.random.normal(k2, (nb, bs, K, r))
+    pool_k = jax.random.normal(k1, (nb, K, bs, r))
+    pool_v = jax.random.normal(k2, (nb, K, bs, r))
     qg = jax.random.normal(k3, (B, K, G, hd))
     # calibrated-style link matrices (r, hd); identity-ish at full rank
     uk = pcache.kv_projection(jax.random.normal(k1, (64, hd)), r)[1]

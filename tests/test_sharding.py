@@ -16,8 +16,6 @@ from repro.optim.adamw import AdamW
 
 
 def _mesh(multi_pod=False):
-    # shd.abstract_mesh papers over the AbstractMesh constructor change
-    # between jax 0.4.x and 0.5+
     if multi_pod:
         return shd.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
     return shd.abstract_mesh((16, 16), ("data", "model"))
@@ -192,7 +190,7 @@ def test_paged_cache_specs_divisible():
     cache, pc = sp.paged_cache_specs(cfg, SHAPES["decode_32k"])
     specs = shd.paged_cache_pspecs(cache, cfg, mesh)
     _check_divisible(cache, specs, mesh, "paged-olmo")
-    assert tuple(specs["k"]) == (None, None, None, "model", None)
+    assert tuple(specs["k"]) == (None, None, "model", None, None)
     toks, table, ctx, active = shd.paged_decode_pspecs(
         cfg, SHAPES["decode_32k"].global_batch, pc.max_blocks_per_seq,
         mesh)
@@ -212,7 +210,7 @@ def test_paged_cache_specs_kernel_pins_kv_heads():
     cfg = get_config("olmo-1b")
     cache, pc = sp.paged_cache_specs(cfg, SHAPES["decode_32k"])
     specs = shd.paged_cache_pspecs(cache, cfg, mesh, kernel=True)
-    assert tuple(specs["k"]) == (None, None, None, "model", None)
+    assert tuple(specs["k"]) == (None, None, "model", None, None)
     # smoke olmo: K=4 does not divide 16; the einsum path falls back to
     # the rank axis, the kernel path must replicate
     scfg = get_smoke("olmo-1b")
@@ -237,7 +235,7 @@ def test_paged_cache_specs_cur_kv():
     cache = jax.eval_shape(lambda: init_paged_cache(cfg, pc))
     specs = shd.paged_cache_pspecs(cache, cfg, mesh)
     _check_divisible(cache, specs, mesh, "paged-curkv")
-    assert tuple(specs["k"]) == (None, None, None, "model", None)
+    assert tuple(specs["k"]) == (None, None, "model", None, None)
     assert specs["proj"]["uk"] is None          # replicated
     # CUR-KV pool stores r of head_dim feature columns
     assert cache["k"].shape[-1] == 64
