@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.configs.base import ATTN, ATTN_LOCAL, MAMBA, MLP, MOE
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import NULL_TRACER
 from repro.models import attention as attn
 from repro.models import mamba as mb
 from repro.models.layers import norm
@@ -115,8 +116,13 @@ def _jitted_step(cfg, mesh):
     return _STEP_CACHE[key]
 
 
-def calibrate(params, cfg, batches, mesh=None) -> CalibStats:
-    """batches: list of batch dicts (each one calibration micro-batch)."""
+def calibrate(params, cfg, batches, mesh=None, tracer=None) -> CalibStats:
+    """batches: list of batch dicts (each one calibration micro-batch).
+
+    ``tracer`` records the sub-steps (``calibrate.batch`` per micro-batch,
+    ``calibrate.wait`` for the final transfer); the caller's span around
+    the call names the stage."""
+    tracer = tracer or NULL_TRACER
     # default-registry timings (NULL no-ops unless obs is enabled): the
     # first batch carries the jit compile, so the per-batch histogram
     # makes compile-vs-steady cost visible without perturbing the pass
@@ -136,20 +142,22 @@ def calibrate(params, cfg, batches, mesh=None) -> CalibStats:
     n_tokens = 0
 
     for batch in batches:
-        t0 = time.perf_counter()
-        shape = (batch["tokens"] if cfg.input_mode == "tokens"
-                 else batch["embeds"]).shape
-        n_tokens += shape[0] * shape[1]
-        hs, act_sq = step(params, batch)
-        hidden_chunks.append(hs)                    # (L+1, B, D) on device
-        for li, acc in enumerate(act_sq):
-            for t, sq in acc.items():
-                prev = act_acc[li].get(t)
-                act_acc[li][t] = sq if prev is None else prev + sq
-        h_batch.observe(time.perf_counter() - t0)
+        with tracer.span("calibrate.batch"):
+            t0 = time.perf_counter()
+            shape = (batch["tokens"] if cfg.input_mode == "tokens"
+                     else batch["embeds"]).shape
+            n_tokens += shape[0] * shape[1]
+            hs, act_sq = step(params, batch)
+            hidden_chunks.append(hs)                # (L+1, B, D) on device
+            for li, acc in enumerate(act_sq):
+                for t, sq in acc.items():
+                    prev = act_acc[li].get(t)
+                    act_acc[li][t] = sq if prev is None else prev + sq
+            h_batch.observe(time.perf_counter() - t0)
 
-    hidden, act_np = jax.device_get(
-        (jnp.concatenate(hidden_chunks, axis=1), act_acc))
+    with tracer.span("calibrate.wait"):
+        hidden, act_np = jax.device_get(
+            (jnp.concatenate(hidden_chunks, axis=1), act_acc))
     c_time.inc(time.perf_counter() - t_pass)
     c_toks.inc(n_tokens)
     return CalibStats(hidden=hidden, act_sq=act_np, n_tokens=n_tokens)

@@ -19,6 +19,15 @@ pinv link solve. One host transfer per class instead of several per
 weight; this is what makes one-shot CURing wall-clock competitive
 (paper Table 1: Llama3.1-8B in 129 s).
 
+The per-weight chain is named for the profiler (``jax.named_scope``):
+``cure_svd`` (the WANDA scores and the selection SVD: the scores fuse
+into the SVD's first fusions), ``cure_deim`` (both DEIM calls),
+``cure_link`` (U = C+ W R+) and ``cure_check`` (the reconstruction
+error and the Theorem 3.1 bound). A ``tracer`` passed to
+``compress_model`` records the host sub-steps (``compress.distances``,
+``compress.unroll``, ``compress.class`` with ``.stack`` and ``.wait``,
+``compress.fold`` with ``.wait``).
+
 ``"loop"`` is the original per-weight reference path. Both consume the
 same per-weight PRNG key stream (split in network order before
 dispatch), so on a fixed seed they produce identical row/col selections
@@ -40,7 +49,9 @@ import numpy as np
 
 from repro.configs.base import CURConfig, ModelConfig
 from repro.core import angular
+from repro.obs import compiles
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import NULL_TRACER
 from repro.core.calibrate import CalibStats, iter_layer_params
 from repro.core.cur import (
     cur_from_indices,
@@ -112,17 +123,22 @@ def select_indices(W: jnp.ndarray, r: int, method: str,
               else lambda M, rr: randomized_svd(M, rr, key))
     aux = {}
     if method == "wanda_deim":
-        S = wanda_scores(W, jnp.asarray(act_sq))
-        P, sig, Q = svd_fn(S, min(r + 1, min(W.shape)))
-        p, q = deim(P[:, :r]), deim(Q[:, :r])
+        with jax.named_scope("cure_svd"):
+            S = wanda_scores(W, jnp.asarray(act_sq))
+            P, sig, Q = svd_fn(S, min(r + 1, min(W.shape)))
+        with jax.named_scope("cure_deim"):
+            p, q = deim(P[:, :r]), deim(Q[:, :r])
         aux = {"P": P, "Q": Q, "sig": sig}
     elif method == "wanda":
         S = wanda_scores(W, jnp.asarray(act_sq))
         p = _top_k_indices(jnp.linalg.norm(S, axis=1), r)
         q = _top_k_indices(jnp.linalg.norm(S, axis=0), r)
     elif method == "deim":
-        P, sig, Q = svd_fn(W.astype(jnp.float32), min(r + 1, min(W.shape)))
-        p, q = deim(P[:, :r]), deim(Q[:, :r])
+        with jax.named_scope("cure_svd"):
+            P, sig, Q = svd_fn(W.astype(jnp.float32),
+                               min(r + 1, min(W.shape)))
+        with jax.named_scope("cure_deim"):
+            p, q = deim(P[:, :r]), deim(Q[:, :r])
         aux = {"P": P, "Q": Q, "sig": sig}
     elif method == "weight":
         Wf = W.astype(jnp.float32)
@@ -253,33 +269,34 @@ def _compress_class_batched(Ws, acts, keys, *, r: int, selection: str,
 
     def one(W, act, key):
         p, q, aux = select_indices(W, r, selection, act, key, svd)
-        Wf = W.astype(jnp.float32)
-        C, U, R = cur_from_indices(Wf, p, q)
-        err = jnp.linalg.norm(Wf - C @ U @ R)
-        if "P" in aux and aux["sig"].shape[0] > r:
-            bound = spectral_error_bound(
-                aux["P"][:, :r], aux["Q"][:, :r], aux["sig"], p, q)
-        else:
-            bound = jnp.float32(jnp.nan)
+        with jax.named_scope("cure_link"):
+            Wf = W.astype(jnp.float32)
+            C, U, R = cur_from_indices(Wf, p, q)
+        with jax.named_scope("cure_check"):
+            err = jnp.linalg.norm(Wf - C @ U @ R)
+            if "P" in aux and aux["sig"].shape[0] > r:
+                bound = spectral_error_bound(
+                    aux["P"][:, :r], aux["Q"][:, :r], aux["sig"], p, q)
+            else:
+                bound = jnp.float32(jnp.nan)
+            frow = jnp.linalg.norm(W)
         return {"p": p, "q": q, "C": C, "U": U, "R": R, "err": err,
-                "frow": jnp.linalg.norm(W), "bound": bound}
+                "frow": frow, "bound": bound}
 
     return jax.vmap(one)(Ws, acts, keys)
 
 
-# shape-class signatures whose jit compile already happened — the first
-# call per signature re-runs once so WeightInfo.seconds reports warm
-# execution, not the one-time XLA compile (which stages_s.compress /
-# CompressInfo.seconds_total still include)
-_WARM_CLASSES: set = set()
-
-
-def _compress_batched(work: List[_WorkItem], cur_cfg: CURConfig):
+def _compress_batched(work: List[_WorkItem], cur_cfg: CURConfig, tracer):
     """Run the work list grouped by (m, n, r) shape-class; returns
     (leaf, WeightInfo) per item, in work-list order. The rank joins the
     class key so per-weight overrides (``CURConfig.ranks``) batch
     correctly — same-shape weights at different planned ranks land in
-    different vmapped calls."""
+    different vmapped calls.
+
+    ``WeightInfo.seconds`` is the class's time over its weights; on a
+    process's first call of a class that includes the compile (the
+    ``compress.class`` span's ``programs`` attr counts the programs
+    obtained)."""
     classes: Dict[Tuple[int, int, int], List[int]] = {}
     for i, it in enumerate(work):
         classes.setdefault(tuple(it.W.shape) + (it.rank,), []).append(i)
@@ -287,35 +304,36 @@ def _compress_batched(work: List[_WorkItem], cur_cfg: CURConfig):
     results: List[Optional[Tuple[dict, WeightInfo]]] = [None] * len(work)
     for (m, n, r), idxs in classes.items():
         t0 = time.perf_counter()
-        Ws = jnp.stack([work[i].W for i in idxs])
-        acts = jnp.stack([
-            jnp.asarray(work[i].act, jnp.float32) if work[i].act is not None
-            else jnp.zeros((m,), jnp.float32) for i in idxs])
-        keys = jnp.stack([work[i].key for i in idxs])
-
-        def call():
-            return _compress_class_batched(
+        with tracer.span("compress.class", m=m, n=n, r=r,
+                         k=len(idxs)) as span:
+            if tracer.enabled:
+                programs0 = compiles.jit_programs()[0]
+            with tracer.span("compress.class.stack"):
+                Ws = jnp.stack([work[i].W for i in idxs])
+                acts = jnp.stack([
+                    jnp.asarray(work[i].act, jnp.float32)
+                    if work[i].act is not None
+                    else jnp.zeros((m,), jnp.float32) for i in idxs])
+                keys = jnp.stack([work[i].key for i in idxs])
+            out = _compress_class_batched(
                 Ws, acts, keys, r=r, selection=cur_cfg.selection,
                 svd=cur_cfg.svd)
-
-        sig = (len(idxs), m, n, str(Ws.dtype), r, cur_cfg.selection,
-               cur_cfg.svd)
-        if sig not in _WARM_CLASSES:
-            jax.block_until_ready(call())        # compile + first run
-            _WARM_CLASSES.add(sig)
-            t0 = time.perf_counter()             # time the warm run only
-        out = call()
-        # ONE host transfer per class for the scalar/index fields; the
-        # big factors stay device-resident in the returned leaves
-        ps, qs, errs, frows, bounds = jax.device_get(
-            (out["p"], out["q"], out["err"], out["frow"], out["bound"]))
+            # ONE host transfer per class for the scalar/index fields;
+            # the big factors stay device-resident in the returned leaves
+            with tracer.span("compress.class.wait"):
+                ps, qs, errs, frows, bounds = jax.device_get(
+                    (out["p"], out["q"], out["err"], out["frow"],
+                     out["bound"]))
+            if tracer.enabled:
+                span.set(programs=compiles.jit_programs()[0] - programs0)
         dt = (time.perf_counter() - t0) / len(idxs)
-        # per-shape-class warm timing; the label space is open-ended but
+        # per-shape-class timing; the label space is open-ended but
         # small in practice, so overflow degrades to NULL instead of
         # raising mid-compression
         obs_metrics.default_registry().histogram(
             "repro_compress_class_s",
-            "warm per-weight seconds by (m,n,r) shape-class",
+            "per-weight seconds by (m,n,r) shape-class (a process's "
+            "first call of a class includes its compile)",
             labels=("shape",), overflow="drop").labels(
             shape=f"{m}x{n}r{r}").observe(dt)
         before, unfolded, folded, deployed = _param_counts(
@@ -398,43 +416,53 @@ def _cur_work_list(params, cfg: ModelConfig, cur_cfg: CURConfig,
 
 
 def compress_model(params, cfg: ModelConfig, cur_cfg: CURConfig,
-                   calib: CalibStats, layers: Optional[List[int]] = None):
-    """Returns (new_params, new_cfg, CompressInfo)."""
+                   calib: CalibStats, layers: Optional[List[int]] = None,
+                   tracer=None):
+    """Returns (new_params, new_cfg, CompressInfo).
+
+    ``tracer`` records the sub-steps (module docstring); the caller's
+    span around the call names the stage. ``CompressInfo.seconds_fold``
+    is the ``compress.fold`` span: every fold dispatched, then one wait
+    for all of them."""
+    tracer = tracer or NULL_TRACER
     t_start = time.perf_counter()
-    distances = angular.layer_distances(calib.hidden)
-    if layers is None:
-        layers = angular.select_layers(
-            distances, cur_cfg.n_compress_layers,
-            cur_cfg.layer_selection, cur_cfg.seed)
+    with tracer.span("compress.distances"):
+        distances = angular.layer_distances(calib.hidden)
+        if layers is None:
+            layers = angular.select_layers(
+                distances, cur_cfg.n_compress_layers,
+                cur_cfg.layer_selection, cur_cfg.seed)
     layer_set = set(layers)
-    _validate_ranks(params, cfg, cur_cfg, layer_set)
+    with tracer.span("compress.unroll"):
+        _validate_ranks(params, cfg, cur_cfg, layer_set)
+        new_cfg = unrolled_config(cfg)
+        new_params = unroll_params(params, cfg)
+        work = _cur_work_list(params, cfg, cur_cfg, calib, layer_set)
 
-    new_cfg = unrolled_config(cfg)
-    new_params = unroll_params(params, cfg)
-
-    work = _cur_work_list(params, cfg, cur_cfg, calib, layer_set)
     if cur_cfg.pipeline == "loop":
         results = [compress_weight(it.W, it.name, it.layer, cur_cfg,
                                    it.act, it.key, rank=it.rank)
                    for it in work]
     elif cur_cfg.pipeline == "batched":
-        results = _compress_batched(work, cur_cfg)
+        results = _compress_batched(work, cur_cfg, tracer)
     else:
         raise ValueError(cur_cfg.pipeline)
 
-    infos: List[WeightInfo] = []
+    # Eq. 2 guard, deployed form
+    kept = [(it, leaf, info) for it, (leaf, info) in zip(work, results)
+            if info.params_after < info.params_before]
     seconds_fold = 0.0
-    for it, (leaf, info) in zip(work, results):
-        if info.params_after >= info.params_before:
-            continue                             # Eq. 2 guard, deployed form
-        if cur_cfg.fold_u:
-            t_fold = time.perf_counter()
-            leaf = fold_cur(leaf)
-            jax.block_until_ready(leaf["CU"])
-            seconds_fold += time.perf_counter() - t_fold
+    if cur_cfg.fold_u:
+        t_fold = time.perf_counter()
+        with tracer.span("compress.fold", k=len(kept)):
+            kept = [(it, fold_cur(leaf), info) for it, leaf, info in kept]
+            with tracer.span("compress.fold.wait"):
+                jax.block_until_ready([leaf["CU"] for _, leaf, _ in kept])
+        seconds_fold = time.perf_counter() - t_fold
+    for it, leaf, info in kept:
         block = new_params["groups"][it.layer][0]
         block[it.name] = jax.tree.map(lambda a: a[None], leaf)
-        infos.append(info)
+    infos: List[WeightInfo] = [info for _, _, info in kept]
 
     cinfo = CompressInfo(
         distances=distances, layers=sorted(layer_set), weights=infos,

@@ -61,9 +61,40 @@ def _smoke_generate(params, cfg, *, n_requests: int, prompt_len: int,
     return int(out.tokens.size), "legacy"
 
 
+def _plan(args, params, cfg, ccfg, calib):
+    """Per-weight ranks from --plan or a --budget-*, else uniform:
+    returns (ccfg, plan, plan_source, layers)."""
+    if args.plan:
+        plan = CompressionPlan.load(args.plan)
+        if plan.provenance.get("cfg_hash") != config_hash(cfg):
+            print(f"  WARNING: plan {args.plan} was computed for a "
+                  f"different model config (cfg_hash mismatch) — "
+                  f"selections may not reproduce")
+        # the plan pins everything the key stream + selections depend on
+        return (plan.to_cur_config(
+            dataclasses.replace(ccfg, pipeline=args.pipeline)),
+            plan, "file", plan.layers)
+    if args.budget is not None:
+        kind, value = args.budget
+        plan, _ = plan_for_model(
+            params, cfg, ccfg, calib, budget_kind=kind, budget_value=value,
+            n_layers=args.layers, grid=args.grid, solver=args.solver,
+            arch=cfg.name)
+        if args.emit_plan:
+            os.makedirs(os.path.dirname(args.emit_plan) or ".",
+                        exist_ok=True)
+            plan.save(args.emit_plan)
+        return (plan.to_cur_config(
+            dataclasses.replace(ccfg, pipeline=args.pipeline)),
+            plan, "budget", plan.layers)
+    return ccfg, None, "uniform", None
+
+
 def cure(args) -> dict:
     # per-stage timing lives on a span tracer (always on — it IS the
-    # stages_s report); --trace additionally writes the Perfetto JSON
+    # stages_s report, from the undotted stage spans; the dotted ones
+    # are calibrate's and compress_model's sub-steps); --trace
+    # additionally writes the Perfetto JSON
     tracer = getattr(args, "tracer", None) or obs.Tracer(
         enabled=True, process="repro.cure")
     if getattr(args, "obs", False):
@@ -71,7 +102,7 @@ def cure(args) -> dict:
     prof = obs.JaxProfiler(
         os.path.join(getattr(args, "obs_out", None) or "results/obs/cure",
                      "jaxprof")
-        if getattr(args, "prof", False) else None, tracer=tracer)
+        if getattr(args, "prof", False) else None)
     t_total = time.perf_counter()
 
     # ---- init ---------------------------------------------------------
@@ -88,49 +119,26 @@ def cure(args) -> dict:
                                 global_batch=args.calib_batch,
                                 seed=args.seed))
     batches = [ds.batch_at(i) for i in range(args.calib_batches)]
-    with tracer.span("calibrate"), prof.scope("calibrate"):
-        calib = calibrate(params, cfg, batches)
-
-    # ---- plan (repro.plan: budget -> per-weight ranks) ----------------
     ccfg = CURConfig(r_max=args.r_max, n_compress_layers=args.layers,
                      selection=args.selection, svd=args.svd,
                      fold_u=not args.no_fold, pipeline=args.pipeline,
                      seed=args.seed)
-    plan, plan_source, layers = None, "uniform", None
-    t_plan = time.perf_counter()
-    if args.plan:
-        plan = CompressionPlan.load(args.plan)
-        plan_source = "file"
-        if plan.provenance.get("cfg_hash") != config_hash(cfg):
-            print(f"  WARNING: plan {args.plan} was computed for a "
-                  f"different model config (cfg_hash mismatch) — "
-                  f"selections may not reproduce")
-        # the plan pins everything the key stream + selections depend on
-        ccfg = plan.to_cur_config(
-            dataclasses.replace(ccfg, pipeline=args.pipeline))
-        layers = plan.layers
-    elif args.budget is not None:
-        kind, value = args.budget
-        plan, _ = plan_for_model(
-            params, cfg, ccfg, calib, budget_kind=kind, budget_value=value,
-            n_layers=args.layers, grid=args.grid, solver=args.solver,
-            arch=cfg.name)
-        plan_source = "budget"
-        ccfg = plan.to_cur_config(
-            dataclasses.replace(ccfg, pipeline=args.pipeline))
-        layers = plan.layers
-        if args.emit_plan:
-            os.makedirs(os.path.dirname(args.emit_plan) or ".",
-                        exist_ok=True)
-            plan.save(args.emit_plan)
-    tracer.add_span("plan", t_plan, time.perf_counter() - t_plan)
+    # one --prof capture holds calibrate, plan and compress: the
+    # tracer's spans land in it beside the device's operations
+    with prof.scope():
+        with tracer.span("calibrate"):
+            calib = calibrate(params, cfg, batches, tracer=tracer)
 
-    # ---- compress + fold ----------------------------------------------
-    t0 = time.perf_counter()
-    with prof.scope("compress"):
-        cparams, ccfg_model, info = compress_model(params, cfg, ccfg,
-                                                   calib, layers=layers)
-    dt = time.perf_counter() - t0
+        # ---- plan (repro.plan: budget -> per-weight ranks) ------------
+        with tracer.span("plan"):
+            ccfg, plan, plan_source, layers = _plan(
+                args, params, cfg, ccfg, calib)
+
+        # ---- compress + fold ------------------------------------------
+        t0 = time.perf_counter()
+        cparams, ccfg_model, info = compress_model(
+            params, cfg, ccfg, calib, layers=layers, tracer=tracer)
+        dt = time.perf_counter() - t0
     # fold time is measured inside compress_model; split the wall span
     # into back-to-back compress/fold spans so durations() reports both
     tracer.add_span("compress", t0, dt - info.seconds_fold)
@@ -189,7 +197,7 @@ def cure(args) -> dict:
             prompt_len=args.prompt_len, new_tokens=args.new_tokens,
             max_concurrency=args.max_concurrency, seed=args.seed)
 
-    stages = tracer.durations()
+    stages = {k: v for k, v in tracer.durations().items() if "." not in k}
     stages["total"] = time.perf_counter() - t_total
 
     w = info.weights
@@ -326,8 +334,8 @@ def main(argv=None):
                     help="write a Chrome/Perfetto trace.json of the "
                          "stage spans to --obs-out")
     ap.add_argument("--prof", action="store_true",
-                    help="capture a jax.profiler trace per stage under "
-                         "--obs-out/jaxprof")
+                    help="capture one jax.profiler trace of calibrate, "
+                         "plan and compress under --obs-out/jaxprof")
     args = ap.parse_args(argv)
     if args.ckpt_dir is None:
         args.ckpt_dir = os.path.join("results", "cure", args.arch)

@@ -116,8 +116,7 @@ def main(argv=None):
         obs.enable()
     tracer = obs.Tracer(enabled=args.trace, process="repro.plan")
     prof = obs.JaxProfiler(
-        os.path.join(args.obs_out, "jaxprof") if args.prof else None,
-        tracer=tracer)
+        os.path.join(args.obs_out, "jaxprof") if args.prof else None)
 
     def _export():
         if args.obs or args.trace:
@@ -157,7 +156,7 @@ def main(argv=None):
             heal_ds = SyntheticLM(DataConfig(
                 vocab_size=cfg.vocab_size, seq_len=args.calib_len,
                 global_batch=args.calib_batch, seed=args.seed + 2))
-        with prof.scope("progressive"):
+        with prof.scope():
             res = progressive_cure(
                 params, cfg, budget_kind=kind, budget_value=value,
                 n_layers=args.layers, rounds=args.rounds,
@@ -183,14 +182,16 @@ def main(argv=None):
         return res
 
     t0 = time.perf_counter()
-    with tracer.span("calibrate"), prof.scope("calibrate"):
-        calib = calibrate(params, cfg, batches)
-    with tracer.span("profile_allocate"), prof.scope("profile_allocate"):
-        plan, profile = plan_for_model(
-            params, cfg, ccfg, calib, budget_kind=kind,
-            budget_value=value, n_layers=args.layers,
-            grid=parse_grid(args.grid), solver=args.solver,
-            arch=arch_name)
+    # one --prof capture holds calibrate and the allocation
+    with prof.scope():
+        with tracer.span("calibrate"):
+            calib = calibrate(params, cfg, batches, tracer=tracer)
+        with tracer.span("profile_allocate"):
+            plan, profile = plan_for_model(
+                params, cfg, ccfg, calib, budget_kind=kind,
+                budget_value=value, n_layers=args.layers,
+                grid=parse_grid(args.grid), solver=args.solver,
+                arch=arch_name)
     dt = time.perf_counter() - t0
 
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)

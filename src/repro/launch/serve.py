@@ -209,8 +209,7 @@ def main(argv=None):
         obs.enable()
     tracer = obs.Tracer(enabled=args.trace, process="repro.serve")
     prof = obs.JaxProfiler(
-        os.path.join(args.obs_out, "jaxprof") if args.prof else None,
-        tracer=tracer)
+        os.path.join(args.obs_out, "jaxprof") if args.prof else None)
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.input_mode != "tokens":
@@ -325,7 +324,7 @@ def main(argv=None):
           f"prefill={server._prefill_backend}"
           + (f", window={server.window}" if server.window else "")
           + (f", spec_k={server.spec_k}" if server.spec_k else "") + ")")
-    with prof.scope("serve"):
+    with prof.scope():
         finished, stats = run_continuous(server, workload,
                                          temperature=args.temperature)
     if chaos is not None:

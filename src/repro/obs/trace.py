@@ -11,9 +11,16 @@ track 0, per-request lifecycle spans (queued -> prefill -> decode-window
 -> spec-draft/verify -> done) on ``track = rid + 1`` so every request
 renders as its own swimlane.
 
+Each span of an enabled tracer is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``: under a
+``jax.profiler`` capture it lands on the host plane of the same
+``.xplane.pb`` as the device's operations, on the same clock, nested as
+the spans nest. A name ending in ``.wait`` marks the host blocked on the
+device (a ``device_get`` or ``block_until_ready``).
+
 A disabled tracer is free: ``span()`` returns one shared null context
 manager and ``event()`` returns immediately — no object is allocated
-per call.
+per call and the profiler is never called.
 """
 from __future__ import annotations
 
@@ -22,7 +29,13 @@ import json
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+from repro.obs import compiles
+
 ENGINE_TRACK = 0
+# prefix of the spans' names in a jax.profiler capture
+PROFILER_PREFIX = "repro."
 
 
 def request_track(rid: int) -> int:
@@ -48,7 +61,7 @@ NULL_CTX = _NullCtx()
 
 
 class _SpanCtx:
-    __slots__ = ("tracer", "name", "track", "attrs", "t0")
+    __slots__ = ("tracer", "name", "track", "attrs", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: int,
                  attrs: Optional[dict]):
@@ -57,6 +70,7 @@ class _SpanCtx:
         self.track = track
         self.attrs = attrs
         self.t0 = 0.0
+        self.ann = None
 
     def set(self, **attrs):
         """Attach attributes from inside the span body."""
@@ -66,12 +80,15 @@ class _SpanCtx:
         return self
 
     def __enter__(self):
+        self.ann = TraceAnnotation(PROFILER_PREFIX + self.name)
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self.tracer.add_span(self.name, self.t0,
-                             time.perf_counter() - self.t0,
+        dur = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+        self.tracer.add_span(self.name, self.t0, dur,
                              track=self.track, attrs=self.attrs)
         return False
 
@@ -87,6 +104,8 @@ class Tracer:
         self.spans: List[dict] = []
         self.events: List[dict] = []
         self._track_names: Dict[int, str] = {ENGINE_TRACK: "engine"}
+        if enabled:
+            compiles.watch(self)
 
     def name_track(self, track: int, name: str) -> None:
         self._track_names[track] = name
@@ -115,7 +134,10 @@ class Tracer:
     def add_span(self, name: str, t0: float, dur: float,
                  track: int = ENGINE_TRACK,
                  attrs: Optional[dict] = None) -> None:
-        """Record an already-timed span (t0 in perf_counter seconds)."""
+        """Record an already-timed span (t0 in perf_counter seconds).
+
+        It is recorded after the fact, so it cannot annotate a
+        ``jax.profiler`` capture: only ``span()`` appears there."""
         if not self.enabled:
             return
         self.spans.append({"name": name, "t0": t0 - self.epoch,

@@ -173,7 +173,7 @@ def progressive_cure(params, cfg: ModelConfig, *,
         with tracer.span("round", round=i):
             with tracer.span("calibrate", round=i):
                 calib = calibrate(cur_params, cur_cfg_m,
-                                  list(calib_batches))
+                                  list(calib_batches), tracer=tracer)
             distances = angular.layer_distances(calib.hidden)
             order = sorted(candidates, key=lambda li: distances[li])
             layers_i = sorted(order[:chunks[i]])
@@ -188,7 +188,8 @@ def progressive_cure(params, cfg: ModelConfig, *,
             ccfg = plan.to_cur_config(base)
             with tracer.span("compress", round=i):
                 new_params, new_cfg, _ = compress_model(
-                    cur_params, cur_cfg_m, ccfg, calib, layers=layers_i)
+                    cur_params, cur_cfg_m, ccfg, calib, layers=layers_i,
+                    tracer=tracer)
             ppl_c = perplexity(new_params, new_cfg, eval_batches)
 
             if heal_steps:

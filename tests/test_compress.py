@@ -301,3 +301,89 @@ def test_angular_distance_layer_selection(tiny_cfg, tiny_params, compressed):
     chosen = [info.distances[i] for i in info.layers]
     assert max(chosen) <= max(cands)
     assert sorted(chosen) == sorted(sorted(cands)[:len(chosen)])
+
+
+def test_each_shape_class_runs_once_per_call(tiny_cfg, structured_params,
+                                             monkeypatch):
+    """The first call of a class in a process runs it once, as every
+    later call does: no warm-up rerun."""
+    from repro.core import compress as cmod
+    calls = []
+    inner = cmod._compress_class_batched
+
+    def counted(*a, **kw):
+        calls.append(kw["r"])
+        return inner(*a, **kw)
+    monkeypatch.setattr(cmod, "_compress_class_batched", counted)
+    calib = calibrate(structured_params, tiny_cfg,
+                      [make_batch(tiny_cfg, 2, 32)])
+    # r_max 4: a rank no other test compiles, so the first call compiles
+    ccfg = CURConfig(r_max=4, n_compress_layers=2)
+    _, _, info = compress_model(structured_params, tiny_cfg, ccfg, calib)
+    n_classes = len({(w.shape, w.rank) for w in info.weights})
+    assert len(calls) == n_classes
+    compress_model(structured_params, tiny_cfg, ccfg, calib)
+    assert len(calls) == 2 * n_classes
+
+
+def test_fold_dispatches_every_fold_then_waits_once(
+        tiny_cfg, structured_params, monkeypatch):
+    from repro.core import compress as cmod
+    from repro.obs import Tracer
+    waits = []
+    inner = jax.block_until_ready
+
+    def counted(x):
+        waits.append(len(jax.tree.leaves(x)))
+        return inner(x)
+    monkeypatch.setattr(cmod.jax, "block_until_ready", counted)
+    calib = calibrate(structured_params, tiny_cfg,
+                      [make_batch(tiny_cfg, 2, 32)])
+    ccfg = CURConfig(r_max=8, n_compress_layers=2, fold_u=True)
+    tr = Tracer()
+    _, _, info = compress_model(structured_params, tiny_cfg, ccfg, calib,
+                                tracer=tr)
+    assert waits == [len(info.weights)] and len(info.weights) > 1
+    fold = [s for s in tr.spans if s["name"] == "compress.fold"]
+    assert len(fold) == 1
+    assert info.seconds_fold == pytest.approx(fold[0]["dur"], abs=1e-3)
+
+
+def test_weight_seconds_is_its_class_span_over_k(tiny_cfg,
+                                                 structured_params):
+    """WeightInfo.seconds is the class's time over its weights, and a
+    call whose programs are all compiled obtains none (the compress
+    window's target)."""
+    from repro.obs import Tracer
+    calib = calibrate(structured_params, tiny_cfg,
+                      [make_batch(tiny_cfg, 2, 32)])
+    ccfg = CURConfig(r_max=8, n_compress_layers=2)
+    compress_model(structured_params, tiny_cfg, ccfg, calib)
+    tr = Tracer()
+    _, _, info = compress_model(structured_params, tiny_cfg, ccfg, calib,
+                                tracer=tr)
+    classes = [s for s in tr.spans if s["name"] == "compress.class"]
+    assert sum(s["attrs"]["k"] for s in classes) == len(info.weights)
+    for s in classes:
+        a = s["attrs"]
+        assert a["programs"] == 0
+        ws = [w for w in info.weights
+              if w.shape == (a["m"], a["n"]) and w.rank == a["r"]]
+        assert len(ws) == a["k"]
+        for w in ws:
+            assert w.seconds == pytest.approx(s["dur"] / a["k"], abs=1e-3)
+
+
+def test_compress_chain_is_named_for_the_profiler():
+    """Each part of the per-weight chain carries its scope in the
+    program's op metadata (debug info, which the compile cache's key
+    leaves out)."""
+    from repro.core import compress as cmod
+    k, m, n = 2, 40, 56
+    Ws = jnp.ones((k, m, n), jnp.bfloat16)
+    keys = jax.random.split(jax.random.PRNGKey(0), k)
+    text = cmod._compress_class_batched.lower(
+        Ws, jnp.ones((k, m)), keys, r=8, selection="wanda_deim",
+        svd="exact").as_text(debug_info=True)
+    for scope in ("cure_svd", "cure_deim", "cure_link", "cure_check"):
+        assert scope in text

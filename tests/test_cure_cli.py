@@ -92,3 +92,31 @@ def test_cure_cli_budget_plan_roundtrip(tmp_path):
         assert rep["plan"]["solver"] == "greedy"
         assert {w["name"] for w in rep["weights"]} <= {"wq", "wk", "w_gate"}
     assert _hash_ckpt(tmp_path / "a") == _hash_ckpt(tmp_path / "b")
+
+
+def test_cure_cli_prof_takes_one_capture(tmp_path):
+    """--prof takes one capture that holds calibrate, plan and compress's
+    spans beside the device's operations; --trace writes the inner
+    spans too, while stages_s keeps only the stages."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    obs_out = tmp_path / "obs"
+    report = main([
+        "--arch", "olmo-1b", "--smoke", "--layers", "1", "--r-max", "8",
+        "--calib-batches", "1", "--calib-batch", "1", "--calib-len", "32",
+        "--n-requests", "2", "--prompt-len", "8", "--new-tokens", "4",
+        "--max-concurrency", "2", "--prof", "--trace",
+        "--obs-out", str(obs_out), "--ckpt-dir", str(tmp_path / "ckpt"),
+    ])
+    path, = glob.glob(os.path.join(str(obs_out), "jaxprof", "**",
+                                   "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    for span in ("calibrate", "calibrate.batch", "plan",
+                 "compress.class", "compress.fold.wait"):
+        assert "repro." + span in names
+    assert not any("." in k for k in report["stages_s"])
+    trace = json.loads((obs_out / "trace.json").read_text())
+    assert "compress.class" in {e["name"] for e in trace["traceEvents"]}

@@ -2,6 +2,7 @@
 guard, disabled-mode zero-cost path, Chrome-trace export, Prometheus
 exposition, and end-to-end serving instrumentation (spec on and off)
 plus the ``launch/serve.py --obs --trace`` smoke."""
+import glob
 import json
 import math
 import os
@@ -319,6 +320,104 @@ def test_disabled_tracer_is_null():
     tr.event("y")
     assert tr.spans == [] and tr.events == []
     assert NULL_TRACER.span("z") is NULL_CTX
+
+
+def test_disabled_tracer_makes_no_profiler_call(monkeypatch):
+    from repro.obs import trace as trace_mod
+    calls = []
+
+    def annotation(name):
+        calls.append(name)
+        return NULL_CTX
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", annotation)
+    for tr in (Tracer(enabled=False), NULL_TRACER):
+        with tr.span("x", k=1) as s:
+            s.set(m=2)
+        assert tr.spans == []
+    assert calls == []
+    with Tracer().span("y"):
+        pass
+    assert calls == ["repro.y"]
+
+
+def _profiled_events(logdir):
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+def test_cure_spans_nest_in_the_profiler_capture(tmp_path, tiny_cfg,
+                                                 tiny_params):
+    """calibrate and compress_model under jax.profiler: every span is a
+    repro.* annotation on the capture's host plane, a dotted name lies
+    inside an annotation of its parent name, and the .wait spans (host
+    blocked on the device) are there."""
+    from conftest import make_batch
+    from repro.configs.base import CURConfig
+    from repro.core import calibrate, compress_model
+    tr = Tracer()
+    ccfg = CURConfig(r_max=8, n_compress_layers=2, fold_u=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("calibrate"):
+            calib = calibrate(tiny_params, tiny_cfg,
+                              [make_batch(tiny_cfg, 2, 16)] * 2, tracer=tr)
+        with tr.span("compress"):
+            compress_model(tiny_params, tiny_cfg, ccfg, calib, tracer=tr)
+    events = _profiled_events(str(tmp_path))
+    names = [n[len("repro."):] for n, _, _ in events]
+    assert sorted(names) == sorted(s["name"] for s in tr.spans)
+    for want in ("calibrate.batch", "calibrate.wait", "compress.distances",
+                 "compress.unroll", "compress.class", "compress.class.stack",
+                 "compress.class.wait", "compress.fold",
+                 "compress.fold.wait"):
+        assert want in names
+    for name, t0, t1 in events:
+        parent = "repro." + name[len("repro."):].rsplit(".", 1)[0]
+        if parent == name:
+            continue
+        assert any(p == parent and p0 <= t0 and t1 <= p1
+                   for p, p0, p1 in events), name
+    for s in tr.spans:
+        if s["name"] == "compress.class":
+            assert {"m", "n", "r", "k", "programs"} <= set(s["attrs"])
+
+
+def test_jit_programs_counts_each_program_once():
+    x = jax.numpy.arange(3.0)
+    tr = Tracer()
+    reg = obs.default_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        before = obs.jit_programs()
+        c0 = reg.counter("repro_jit_programs_total").value
+
+        @jax.jit
+        def triple_plus_one(v):
+            return v * 3.0 + 1.0
+        triple_plus_one(x).block_until_ready()
+        mid = obs.jit_programs()
+        triple_plus_one(x).block_until_ready()
+        after = obs.jit_programs()
+        c1 = reg.counter("repro_jit_programs_total").value
+    finally:
+        if not was:
+            reg.disable()
+    assert mid[0] - before[0] == 1
+    assert after == mid
+    assert c1 - c0 == 1
+    assert [e["attrs"]["fun_name"] for e in tr.events
+            if e["name"] == "compile"] == ["jit(triple_plus_one)"]
+    y = x + 1.0
+    before = obs.jit_programs()
+    obs.compiles.install()                      # idempotent
+    triple_plus_one(y).block_until_ready()      # same shape: no program
+    assert obs.jit_programs() == before
 
 
 # ---------------------------------------------------------------------------
