@@ -3,9 +3,10 @@
 Given the leading-r singular vectors V (m, r) of an importance matrix, DEIM
 picks exactly r distinct row indices: index j is the position of the largest
 interpolation residual of singular vector j against the previously selected
-rows (Sorensen & Embree 2016, Alg. 1). Implemented jit-compatibly with
-fixed-shape padded solves (O(r^4) total — fine for r <= 512; the SVD that
-precedes it dominates at paper scale).
+rows (Sorensen & Embree 2016, Alg. 1). After j steps of Gaussian elimination
+with partial pivoting, column j of V's Schur complement is that residual
+(Chaturantabut & Sorensen 2010; Sorensen & Embree 2016, §2), so the whole
+selection is r elementwise rank-1 updates in float32: O(m r^2) work, no solve.
 """
 from __future__ import annotations
 
@@ -14,30 +15,28 @@ import jax.numpy as jnp
 
 
 def deim(V: jnp.ndarray) -> jnp.ndarray:
-    """V: (m, r) orthonormal-ish columns. Returns (r,) distinct indices."""
-    V = V.astype(jnp.float32)
-    m, r = V.shape
-    p0 = jnp.argmax(jnp.abs(V[:, 0])).astype(jnp.int32)
-    p = jnp.zeros((r,), jnp.int32).at[0].set(p0)
-    visited = jnp.zeros((m,), bool).at[p0].set(True)
+    """V: (m, r) orthonormal-ish columns. Returns (r,) distinct indices.
+
+    Step j reads only column j's residual, so ``deim(V[:, :r]) ==
+    deim(V)[:r]``. A zero pivot eliminates nothing, so a rank-deficient V
+    still yields r distinct indices."""
+    R = V.astype(jnp.float32)                            # (m, r) residuals
+    m, r = R.shape
+    cols = jnp.arange(r)
 
     def body(j, state):
-        p, visited = state
-        rows = V[p, :]                                   # (r, r)
-        jr = jnp.arange(r)
-        mask = jr < j
-        sq = mask[:, None] & mask[None, :]
-        A = jnp.where(sq, rows, 0.0)
-        A = A + jnp.diag(jnp.where(mask, 0.0, 1.0))      # identity padding
-        rhs = jnp.where(mask, rows[:, j], 0.0)
-        c = jnp.linalg.solve(A, rhs)                     # zeros beyond j
-        res = V[:, j] - V @ jnp.where(mask, c, 0.0)
-        score = jnp.where(visited, -1.0, jnp.abs(res))
-        pj = jnp.argmax(score).astype(jnp.int32)
-        return p.at[j].set(pj), visited.at[pj].set(True)
+        R, res, p, visited = state                       # res = R[:, j]
+        pj = jnp.argmax(jnp.where(visited, -1.0, jnp.abs(res)))
+        piv = res[pj]
+        l = jnp.where(piv == 0.0, 0.0, res / jnp.where(piv == 0.0, 1.0, piv))
+        R = R - l[:, None] * R[pj][None, :]
+        # column j + 1 as an exact masked sum, fused into the update: on
+        # TPU a dynamic column slice of R copies all of R every step
+        res = jnp.where(cols == j + 1, R, 0.0).sum(axis=1)
+        return R, res, p.at[j].set(pj), visited.at[pj].set(True)
 
-    p, _ = jax.lax.fori_loop(1, r, body, (p, visited))
-    return p
+    state = (R, R[:, 0], jnp.zeros((r,), jnp.int32), jnp.zeros((m,), bool))
+    return jax.lax.fori_loop(0, r, body, state)[2]
 
 
 def deim_pair(P: jnp.ndarray, Q: jnp.ndarray):

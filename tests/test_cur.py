@@ -54,6 +54,58 @@ def test_deim_interpolation_property():
         assert np.max(np.abs(res[p[:j]])) < 1e-4
 
 
+def _orthonormal(m, r, seed):
+    A = np.random.default_rng(seed).standard_normal((m, r))
+    return np.linalg.qr(A)[0]
+
+
+def _deim_oracle(V):
+    """Sorensen & Embree 2016, Alg. 1 in float64 with a solve per step.
+    Returns the indices and, per step, the gap between the two largest
+    residual magnitudes over the largest."""
+    m, r = V.shape
+    res, p, gaps = V[:, 0].copy(), [], []
+    for j in range(r):
+        if j:
+            c = np.linalg.solve(V[p, :j], V[p, j])
+            res = V[:, j] - V[:, :j] @ c
+            res[p] = 0.0
+        top2 = np.sort(np.abs(res))[-2:]
+        gaps.append((top2[1] - top2[0]) / top2[1])
+        p.append(int(np.argmax(np.abs(res))))
+    return np.array(p), np.array(gaps)
+
+
+# two seeds per shape whose oracle keeps every step's top two residuals
+# more than 1e-3 apart (most seeds at 512 x 64 do not)
+@pytest.mark.parametrize("m,r,seed", [(64, 8, 0), (64, 8, 1), (256, 32, 1),
+                                      (256, 32, 2), (512, 64, 2),
+                                      (512, 64, 3)])
+def test_deim_matches_solve_oracle(m, r, seed):
+    """Elimination picks the indices of the solve-based Algorithm 1."""
+    V = _orthonormal(m, r, seed)
+    want, gaps = _deim_oracle(V)
+    assert gaps.min() > 1e-3            # no near-tie the test could flip on
+    np.testing.assert_array_equal(np.asarray(deim(jnp.asarray(V))), want)
+
+
+@pytest.mark.parametrize("r", [16, 48])
+def test_deim_is_prefix_consistent(r):
+    V = jnp.asarray(_orthonormal(256, 64, 5), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(deim(V[:, :r])),
+                                  np.asarray(deim(V))[:r])
+
+
+def test_deim_rank_deficient_gives_distinct_indices():
+    """Two equal columns and one zero column: r distinct indices in range."""
+    V = np.random.default_rng(6).standard_normal((40, 6)).astype(np.float32)
+    V[:, 3] = V[:, 1]
+    V[:, 5] = 0.0
+    p = np.asarray(deim(jnp.asarray(V)))
+    assert len(set(p.tolist())) == 6
+    assert p.min() >= 0 and p.max() < 40
+
+
 # ---------------------------------------------------------------------------
 # Theorem 3.1 error bound
 # ---------------------------------------------------------------------------
