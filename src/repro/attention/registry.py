@@ -103,6 +103,7 @@ class Caps:
     rank_space: bool = False   # correct at feature dim r != head_dim
     paged: bool = False        # reads KV through a block table
     q_span: bool = False       # multi-position verify layout
+    value_width: bool = False  # values narrower or wider than q/k (MLA)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,7 +150,7 @@ def describe() -> List[dict]:
 def resolve(variant: str, **ctx) -> Backend:
     """First registered backend whose caps cover the request and whose
     availability gate passes. ``ctx`` keys: seq_len, window, q_span,
-    rank_space, static, force (variant-specific pin)."""
+    rank_space, value_width, static, force (variant-specific pin)."""
     cands = _REGISTRY.get(variant)
     if not cands:
         raise KeyError(f"unknown attention variant {variant!r}; "
@@ -160,6 +161,8 @@ def resolve(variant: str, **ctx) -> Backend:
         if ctx.get("q_span", 1) > 1 and not be.caps.q_span:
             continue
         if ctx.get("rank_space", False) and not be.caps.rank_space:
+            continue
+        if ctx.get("value_width", False) and not be.caps.value_width:
             continue
         if be.available(ctx):
             return be
@@ -207,7 +210,7 @@ register("mix", Backend(
     gate=f"{_FLASH_KERNEL_ENV}=auto|1|0 (auto: TPU)"))
 register("mix", Backend(
     "dense_xla", "oracle",
-    Caps(window=True, rank_space=True),
+    Caps(window=True, rank_space=True, value_width=True),
     _mix_dense,
     available=lambda ctx: (ctx.get("seq_len", 0)
                            <= ctx.get("dense_max", xla.DENSE_MAX)
@@ -215,13 +218,13 @@ register("mix", Backend(
     gate="seq_len <= DENSE_MAX"))
 register("mix", Backend(
     "banded_xla", "xla",
-    Caps(window=True, rank_space=True),
+    Caps(window=True, rank_space=True, value_width=True),
     _mix_banded,
     available=lambda ctx: ctx.get("window", 0) > 0,
     gate="window > 0"))
 register("mix", Backend(
     "flash_xla", "xla",
-    Caps(window=False, rank_space=True),
+    Caps(window=False, rank_space=True, value_width=True),
     _mix_flash_xla,
     gate="fallback"))
 
@@ -230,13 +233,15 @@ def mix(qg, k, v, positions, window: int, scale: float, cfg=None, *,
         dense_max: Optional[int] = None):
     """Resolve and run the ``mix`` variant.
 
-    qg (B,S,K,G,d) grouped queries; k,v (B,S,K,d); positions (B,S).
+    qg (B,S,K,G,d) grouped queries; k (B,S,K,d), v (B,S,K,dv);
+    positions (B,S).
     ``dense_max`` overrides the small-S oracle threshold (the models
     layer threads its monkeypatchable module global through here)."""
     S = qg.shape[1]
     static = bool(cfg is not None and cfg.static_loops)
     chunk = cfg.attn_chunk if cfg is not None else xla.CHUNK
     be = resolve("mix", seq_len=S, window=window, static=static,
+                 value_width=v.shape[-1] != qg.shape[-1],
                  dense_max=dense_max if dense_max is not None
                  else xla.DENSE_MAX)
     return be.fn(qg, k, v, positions, positions, window, scale,

@@ -60,21 +60,22 @@ def _flash_chunk_update(carry, s, v_chunk):
 
 def flash_attn(q, k, v, q_pos, kv_pos, scale: float, chunk: int,
                static: bool = False):
-    """Nested-chunk online softmax. q (B,Sq,K,G,d), k/v (B,Skv,K,d).
+    """Nested-chunk online softmax. q (B,Sq,K,G,d), k (B,Skv,K,d),
+    v (B,Skv,K,dv).
 
     ``static=True`` unrolls both chunk loops in Python and *skips* causally
     dead (q, k) chunk pairs — the control flow the Pallas kernel executes
     on TPU (pl.when), used by the dry-run cost compiles so HLO FLOPs count
     loop trips and reflect causal tile skipping."""
     B, Sq, K, G, hd = q.shape
-    Skv = k.shape[1]
+    Skv, dv = k.shape[1], v.shape[-1]
     cq = min(chunk, Sq)
     ck = min(chunk, Skv)
     nq, nk = Sq // cq, Skv // ck
     qc = q.reshape(B, nq, cq, K, G, hd)
     qp = q_pos.reshape(B, nq, cq)
     kc = k.reshape(B, nk, ck, K, hd)
-    vc = v.reshape(B, nk, ck, K, hd)
+    vc = v.reshape(B, nk, ck, K, dv)
     kp = kv_pos.reshape(B, nk, ck)
 
     def chunk_scores(qi, qpi, ki, kpi):
@@ -86,7 +87,7 @@ def flash_attn(q, k, v, q_pos, kv_pos, scale: float, chunk: int,
     def per_qchunk_scan(qi, qpi):
         m0 = jnp.full((B, K, G, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, K, G, cq), jnp.float32)
-        a0 = jnp.zeros((B, K, G, cq, hd), jnp.float32)
+        a0 = jnp.zeros((B, K, G, cq, dv), jnp.float32)
 
         def body(carry, xs):
             ki, vi, kpi = xs
@@ -105,7 +106,7 @@ def flash_attn(q, k, v, q_pos, kv_pos, scale: float, chunk: int,
             qi, qpi = qc[:, i], qp[:, i]
             carry = (jnp.full((B, K, G, cq), NEG_INF, jnp.float32),
                      jnp.zeros((B, K, G, cq), jnp.float32),
-                     jnp.zeros((B, K, G, cq, hd), jnp.float32))
+                     jnp.zeros((B, K, G, cq, dv), jnp.float32))
             last_live = (i * cq + cq - 1) // ck     # causal skip beyond
             for j in range(last_live + 1):
                 s = chunk_scores(qi, qpi, kc[:, j], kp[:, j])
@@ -114,11 +115,11 @@ def flash_attn(q, k, v, q_pos, kv_pos, scale: float, chunk: int,
             o = acc / jnp.maximum(l, 1e-30)[..., None]
             outs.append(o.transpose(0, 3, 1, 2, 4))
         o = jnp.concatenate(outs, axis=1)
-        return o.reshape(B, Sq, K, G, hd).astype(q.dtype)
+        return o.reshape(B, Sq, K, G, dv).astype(q.dtype)
 
     o = jax.lax.map(lambda t: per_qchunk_scan(t[0], t[1]),
                     (qc.swapaxes(0, 1), qp.swapaxes(0, 1)))
-    o = o.swapaxes(0, 1).reshape(B, Sq, K, G, hd)
+    o = o.swapaxes(0, 1).reshape(B, Sq, K, G, dv)
     return o.astype(q.dtype)
 
 
@@ -162,8 +163,8 @@ def banded_attn(q, k, v, q_pos, kv_pos, window: int, scale: float,
     if static:
         outs = [per_qchunk(i, qc[:, i], qp[:, i]) for i in range(nq)]
         o = jnp.concatenate(outs, axis=1)
-        return o.reshape(B, Sq, K, G, hd).astype(q.dtype)
+        return o.reshape(B, Sq, K, G, -1).astype(q.dtype)
     o = jax.lax.map(
         lambda t: per_qchunk(t[0], t[1], t[2]),
         (jnp.arange(nq), qc.swapaxes(0, 1), qp.swapaxes(0, 1)))
-    return o.swapaxes(0, 1).reshape(B, Sq, K, G, hd).astype(q.dtype)
+    return o.swapaxes(0, 1).reshape(B, Sq, K, G, -1).astype(q.dtype)

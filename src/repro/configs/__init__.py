@@ -8,6 +8,7 @@ from repro.configs import (
     llama31_8b,
     mamba2_1_3b,
     mixtral_8x22b,
+    moonlight_16b_a3b,
     musicgen_medium,
     olmo_1b,
     pixtral_12b,
@@ -35,9 +36,11 @@ _MODULES = {
     "pixtral-12b": pixtral_12b,
     "jamba-v0.1-52b": jamba_v0_1_52b,
     "llama3.1-8b": llama31_8b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
 }
 
-# the 10 assigned architectures (the paper's own model is extra)
+# the 10 assigned architectures and moonlight-16b-a3b (the paper's own
+# model is extra)
 ARCHS = tuple(k for k in _MODULES if k != "llama3.1-8b")
 
 

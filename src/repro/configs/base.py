@@ -21,6 +21,7 @@ from typing import Mapping, Optional, Sequence, Tuple
 ATTN = "attn"            # full causal attention
 ATTN_LOCAL = "attn_local"  # sliding-window causal attention
 MAMBA = "mamba"          # Mamba-2 SSD block
+MLA = "mla"              # multi-head latent attention (DeepSeek-V2/V3)
 
 # channel mixers
 MLP = "mlp"              # gated (SwiGLU) or plain (GELU) MLP per config
@@ -30,7 +31,7 @@ MOE = "moe"              # top-k routed mixture of experts
 @dataclass(frozen=True)
 class BlockSpec:
     """One decoder block: a sequence mixer followed by a channel mixer."""
-    mixer: str = ATTN          # ATTN | ATTN_LOCAL | MAMBA
+    mixer: str = ATTN          # ATTN | ATTN_LOCAL | MAMBA | MLA
     mlp: str = MLP             # MLP | MOE
 
     @property
@@ -50,6 +51,12 @@ class ModelConfig:
     window: int = 0            # sliding window size for ATTN_LOCAL
     rope_theta: float = 10_000.0
     qk_norm: bool = False
+    # latent attention (MLA, no query LoRA): keys and values come from a
+    # normed latent of kv_lora_rank; q/k heads are qk_nope + qk_rope wide
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # mlp
     d_ff: int = 0
     mlp_act: str = "silu"      # "silu" (SwiGLU gated) | "gelu" (plain 2-layer)
@@ -60,6 +67,16 @@ class ModelConfig:
     moe_d_ff: int = 0          # expert intermediate dim (kimi uses 2048)
     n_shared_experts: int = 0  # dense shared expert path (kimi-style)
     capacity_factor: float = 1.25
+    # routing: "softmax" scores renormalised over the top-k (Mixtral), or
+    # "sigmoid" scores picked by score + a selection-only bias (when
+    # moe_router_bias), renormalised over the top-k and scaled by
+    # moe_routed_scale (DeepSeek-V3 noaux_tc with one group)
+    moe_scoring: str = "softmax"
+    moe_router_bias: bool = False
+    moe_routed_scale: float = 1.0
+    # the experts this instance holds, (first, stop) of the router's
+    # n_experts (one chip's share under expert parallelism); () = all
+    experts_held: Tuple[int, ...] = ()
     # mamba
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -102,7 +119,9 @@ class ModelConfig:
     # §Perf iteration 3: at 1M-token global batch the TP activation
     # all-reduces dwarf FSDP's weight gathers for dense archs.
     layout: str = "tp"
-    # which weights CURing targets for this family (DESIGN.md §5)
+    # which weights CURing targets for this family (DESIGN.md §5); in an
+    # MoE layer "w_gate" names every held expert's gate, and
+    # "shared.w_gate" the shared experts' gate
     cur_targets: Tuple[str, ...] = ("wq", "wk", "w_gate")
 
     # ---- derived -----------------------------------------------------
@@ -121,6 +140,16 @@ class ModelConfig:
             f"{self.name}: groups describe {len(out)} layers, "
             f"config says {self.n_layers}")
         return tuple(out)
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, stop) of the experts held; every expert when unset."""
+        return tuple(self.experts_held) or (0, self.n_experts)
+
+    @property
+    def n_held_experts(self) -> int:
+        first, stop = self.held_experts
+        return stop - first
 
     @property
     def d_inner(self) -> int:  # mamba inner dim
@@ -143,6 +172,15 @@ class ModelConfig:
                 kv = 2 * d * self.n_kv_heads * hd
                 o = self.n_heads * hd * d
                 total += q + kv + o
+            elif spec.mixer == MLA:
+                H, r = self.n_heads, self.kv_lora_rank
+                qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+                total += d * H * qk                          # wq
+                total += d * (r + self.qk_rope_head_dim)     # wkv_a
+                total += r                                   # kv_norm
+                total += r * H * (self.qk_nope_head_dim
+                                  + self.v_head_dim)         # wk_b, wv_b
+                total += H * self.v_head_dim * d             # wo
             elif spec.mixer == MAMBA:
                 di, st, nh = self.d_inner, self.ssm_state, self.ssm_heads
                 total += d * (2 * di + 2 * st + nh)   # in_proj (zxbcdt fused)
@@ -155,8 +193,10 @@ class ModelConfig:
                 total += n_mats * d * ff
             elif spec.mlp == MOE:
                 ff = self.moe_d_ff or self.d_ff
-                total += self.n_experts * 3 * d * ff
+                total += self.n_held_experts * 3 * d * ff
                 total += d * self.n_experts            # router
+                if self.moe_router_bias:
+                    total += self.n_experts
                 if self.n_shared_experts:
                     total += self.n_shared_experts * 3 * d * ff
             if self.parametric_norm:
@@ -173,7 +213,7 @@ class ModelConfig:
         ff = self.moe_d_ff or self.d_ff
         for spec in self.blocks:
             if spec.mlp == MOE:
-                inactive = (self.n_experts - self.n_experts_per_tok)
+                inactive = max(self.n_held_experts - self.n_experts_per_tok, 0)
                 total -= inactive * 3 * d * ff
         return total
 

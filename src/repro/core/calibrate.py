@@ -4,7 +4,12 @@ collecting, per block,
   - the last-token hidden state entering/leaving every block (for
     angular-distance layer selection), and
   - the accumulated squared input activations of every CURing target weight
-    (for WANDA importance).
+    (for WANDA importance): over every token for a dense weight, the
+    normed latent for MLA's ``wk_b``, and, for an MoE layer's expert
+    weights, over the tokens whose top-k picks that expert, one
+    ``(E_held, m)`` array a layer (ungated: the gate scales the expert's
+    output, not its input). ``shared.*`` targets (the shared experts)
+    see every token.
 
 The instrumented forward for one micro-batch is a single jitted function
 (cached per config, like the serving step cache), and both accumulators
@@ -24,21 +29,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ATTN, ATTN_LOCAL, MAMBA, MLP, MOE
+from repro.configs.base import ATTN, ATTN_LOCAL, MAMBA, MLA, MLP, MOE
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import NULL_TRACER
 from repro.models import attention as attn
 from repro.models import mamba as mb
+from repro.models import mla
 from repro.models.layers import norm
 from repro.models.mlp import mlp_forward
-from repro.models.moe import moe_forward
+from repro.models.moe import moe_forward, route_held
 from repro.models.model import _embed
 
 
 @dataclasses.dataclass
 class CalibStats:
     hidden: np.ndarray            # (L+1, n_samples, D) last-token states
-    act_sq: List[Dict[str, np.ndarray]]   # per-layer: name -> (m,) sum x^2
+    # per layer: name -> (m,) sum x^2; (E_held, m) for an MoE layer's
+    # expert weights, row e over the tokens routed to held expert e
+    act_sq: List[Dict[str, np.ndarray]]
     n_tokens: int
     distances: np.ndarray = None  # filled by compress
 
@@ -58,6 +66,7 @@ def iter_layer_params(params, cfg):
 # target weight -> which normed input feeds it
 _MIXER_TARGETS = {"wq", "wk", "wv", "w_z", "w_x", "w_B", "w_C", "w_dt"}
 _MLP_TARGETS = {"w_gate", "w_up"}
+_SHARED_TARGETS = {"shared.w_gate", "shared.w_up"}
 
 
 def _sq_sum(h: jnp.ndarray) -> jnp.ndarray:
@@ -65,12 +74,20 @@ def _sq_sum(h: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(h.astype(jnp.float32) ** 2, axis=(0, 1))
 
 
+def _routed_sq_sum(h: jnp.ndarray, picked: jnp.ndarray) -> jnp.ndarray:
+    """Per held expert, the sum of squares over the tokens that picked
+    it. h (B, S, m), picked (B*S, E_held) bool -> (E_held, m)."""
+    sq = (h.astype(jnp.float32) ** 2).reshape(-1, h.shape[-1])
+    return jnp.matmul(picked.astype(jnp.float32).T, sq,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def _calib_step(params, cfg, batch, mesh=None):
     """Instrumented forward for one micro-batch (mirrors
     ``model.block_forward``). Returns (hs (L+1, B, D) last-token states,
     per-layer act_sq dicts) — all device arrays."""
     x = _embed(params, cfg, batch)
-    B, S, _ = x.shape
+    B, S, D = x.shape
     positions = jnp.broadcast_to(
         jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     hs = [x[:, -1, :]]
@@ -84,6 +101,11 @@ def _calib_step(params, cfg, batch, mesh=None):
         if spec.mixer in (ATTN, ATTN_LOCAL):
             win = cfg.window if spec.mixer == ATTN_LOCAL else 0
             a = attn.attn_forward(h1, p, cfg, positions, window=win)
+        elif spec.mixer == MLA:
+            with jax.named_scope("mla"):
+                a, c, _ = mla.mla_attend(h1, p, cfg, positions)
+            if "wk_b" in cfg.cur_targets:
+                acc["wk_b"] = _sq_sum(c)
         elif spec.mixer == MAMBA:
             a = mb.mamba_forward(h1, p, cfg)
         else:
@@ -91,8 +113,15 @@ def _calib_step(params, cfg, batch, mesh=None):
         x = x + a
         if spec.mlp in (MLP, MOE):
             h2 = norm(x, p.get("norm2"), cfg)
+            picked = None
+            if spec.mlp == MOE:
+                with jax.named_scope("moe_route"):
+                    _, picked = route_held(h2.reshape(-1, D), p, cfg)
             for t in cfg.cur_targets:
                 if t in _MLP_TARGETS and t in p:
+                    acc[t] = (_sq_sum(h2) if picked is None
+                              else _routed_sq_sum(h2, picked))
+                elif t in _SHARED_TARGETS and "shared" in p:
                     acc[t] = _sq_sum(h2)
             if spec.mlp == MLP:
                 x = x + mlp_forward(h2, p, cfg)

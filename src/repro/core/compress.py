@@ -9,6 +9,15 @@
   3. rebuild the model with per-layer (unrolled) groups so compressed and
      dense layers coexist.
 
+Targets are names in ``cfg.cur_targets``. A dotted name reaches into a
+sub-dict (``shared.w_gate``: the shared experts' gate). In an MoE layer
+a target that holds an expert stack ``(E_held, m, n)`` (``w_gate``)
+gives one work item per held expert, with its own routed activations
+and its own PRNG key; the results are folded back into one CUR stack
+per layer (``{"CU": (E, m, r), "R": (E, r, n)}``), which the MoE layer
+runs through ``_expert_mm``. Ranks of one stack that differ (a plan)
+are padded with zeros to the largest, which leaves the product as it is.
+
 Two execution pipelines (``CURConfig.pipeline``):
 
 ``"batched"`` (default) groups the selected weights by shape-class —
@@ -25,8 +34,10 @@ into the SVD's first fusions), ``cure_deim`` (both DEIM calls),
 ``cure_link`` (U = C+ W R+) and ``cure_check`` (the reconstruction
 error and the Theorem 3.1 bound). A ``tracer`` passed to
 ``compress_model`` records the host sub-steps (``compress.distances``,
-``compress.unroll``, ``compress.class`` with ``.stack`` and ``.wait``,
-``compress.fold`` with ``.wait``).
+``compress.unroll``, ``compress.class`` with ``.stack`` and ``.wait``
+and an ``experts`` attribute counting its expert items,
+``compress.fold`` with ``.wait``, and ``compress.experts`` with
+``.wait``: the expert stacks' assembly and fold).
 
 ``"loop"`` is the original per-weight reference path. Both consume the
 same per-weight PRNG key stream (split in network order before
@@ -52,7 +63,7 @@ from repro.core import angular
 from repro.obs import compiles
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import NULL_TRACER
-from repro.core.calibrate import CalibStats, iter_layer_params
+from repro.core.calibrate import CalibStats
 from repro.core.cur import (
     cur_from_indices,
     exact_svd,
@@ -85,6 +96,13 @@ class WeightInfo:
     # computed ("none"). wanda_deim selects indices on S's singular
     # vectors, so its bound holds for S — NOT for W.
     bound_on: str = "none"
+    # the router's index of the expert this weight belongs to (None for
+    # a weight outside an expert stack)
+    expert: Optional[int] = None
+
+    @property
+    def key(self) -> str:
+        return rank_key(self.layer, self.name, self.expert)
 
 
 @dataclasses.dataclass
@@ -157,35 +175,93 @@ def _bound_on(selection: str) -> str:
     return {"wanda_deim": "wanda", "deim": "weight"}.get(selection, "none")
 
 
-def rank_key(layer: int, name: str) -> str:
-    """The ``CURConfig.ranks`` / ``CompressionPlan.ranks`` key format."""
-    return f"{layer}:{name}"
+def rank_key(layer: int, name: str, expert: Optional[int] = None) -> str:
+    """The ``CURConfig.ranks`` / ``CompressionPlan.ranks`` key format:
+    ``"3:wq"``, ``"3:shared.w_gate"``, and ``"3:w_gate[5]"`` for the
+    router's expert 5 of layer 3."""
+    if expert is None:
+        return f"{layer}:{name}"
+    return f"{layer}:{name}[{expert}]"
 
 
 def resolve_rank(m: int, n: int, layer: int, name: str,
-                 cur_cfg: CURConfig) -> int:
+                 cur_cfg: CURConfig, expert: Optional[int] = None) -> int:
     """Per-weight rank: the ``cur_cfg.ranks`` override when present
     (repro.plan allocations), else the uniform Eq. 2 cap."""
     if cur_cfg.ranks:
-        r = cur_cfg.ranks.get(rank_key(layer, name))
+        r = cur_cfg.ranks.get(rank_key(layer, name, expert))
         if r is not None:
             return int(r)
     return rank_for(m, n, cur_cfg.r_max)
 
 
+def _get_target(block: dict, name: str):
+    """The leaf a target names (``shared.w_gate`` -> block["shared"]
+    ["w_gate"]), or None."""
+    for part in name.split("."):
+        if not isinstance(block, dict) or part not in block:
+            return None
+        block = block[part]
+    return block
+
+
+def _set_target(block: dict, name: str, leaf) -> None:
+    """Put ``leaf`` where ``name`` points, copying the sub-dicts on the
+    way so a dict shared with another tree is never written."""
+    *outer, last = name.split(".")
+    for part in outer:
+        block[part] = dict(block[part])
+        block = block[part]
+    block[last] = leaf
+
+
+def _layer_targets(params, cfg: ModelConfig):
+    """Yield (layer, target, stacked leaf, index in the group's stack) in
+    network order, for every still-dense target of every layer, without
+    slicing anything: a 2-D weight's leaf is (reps, m, n), an expert
+    stack's (reps, E_held, m, n)."""
+    li = 0
+    for gi, (pattern, reps) in enumerate(cfg.groups):
+        gp = params["groups"][gi]
+        for r in range(reps):
+            for pi, _ in enumerate(pattern):
+                for t in cfg.cur_targets:
+                    leaf = _get_target(gp[pi], t)
+                    # absent, or already CUR-compressed (a progressive
+                    # later round)
+                    if leaf is None or isinstance(leaf, dict):
+                        continue
+                    yield li, t, leaf, r
+                li += 1
+
+
+def _held_ids(cfg: ModelConfig):
+    return range(*cfg.held_experts)
+
+
 def _validate_ranks(params, cfg: ModelConfig, cur_cfg: CURConfig,
                     layer_set) -> None:
-    """Every override key must name a still-dense 2-D weight in the target
-    set, lie in a selected layer, and carry a feasible rank."""
+    """Every override key must name a still-dense target weight (an
+    expert by its ``[e]`` key), lie in a selected layer, and carry a
+    feasible rank; a layer's expert stack is listed whole or not at
+    all."""
     if not cur_cfg.ranks:
         return
     valid: Dict[str, Tuple[int, int]] = {}
-    for li, spec, lp in iter_layer_params(params, cfg):
-        for t in cfg.cur_targets:
-            W = lp.get(t)
-            if W is None or isinstance(W, dict) or W.ndim != 2:
-                continue
-            valid[rank_key(li, t)] = W.shape
+    stacks: Dict[str, List[str]] = {}
+    for li, t, leaf, _ in _layer_targets(params, cfg):
+        if leaf.ndim == 3:
+            valid[rank_key(li, t)] = leaf.shape[1:]
+        elif leaf.ndim == 4:
+            keys = [rank_key(li, t, e) for e in _held_ids(cfg)]
+            stacks[rank_key(li, t)] = keys
+            valid.update({k: leaf.shape[2:] for k in keys})
+    for stack, keys in stacks.items():
+        listed = sum(k in cur_cfg.ranks for k in keys)
+        if listed not in (0, len(keys)):
+            raise ValueError(
+                f"rank overrides list {listed} of the {len(keys)} held "
+                f"experts of {stack!r}: list all of them or none")
     for k, r in cur_cfg.ranks.items():
         if k not in valid:
             raise ValueError(
@@ -258,6 +334,7 @@ class _WorkItem:
     act: Optional[np.ndarray]
     key: jax.Array
     rank: int = 0
+    expert: Optional[int] = None     # the router's index, in a stack
 
 
 @functools.partial(jax.jit, static_argnames=("r", "selection", "svd"))
@@ -286,12 +363,23 @@ def _compress_class_batched(Ws, acts, keys, *, r: int, selection: str,
     return jax.vmap(one)(Ws, acts, keys)
 
 
+# The most elements (k m n) that one vmapped class call takes: the largest
+# class of olmo-1b's CURe (10 w_gate of 2048 x 8192). A larger class runs
+# in equal chunks: one chip's 80 expert gates of Moonlight-16B-A3B (2048 x
+# 1408) would need 8 GB of SVD workspace in one call, beside 6.7 GB of
+# weights on a 16 GB v5e.
+CLASS_MAX_ELEMENTS = 10 * 2048 * 8192
+
+
 def _compress_batched(work: List[_WorkItem], cur_cfg: CURConfig, tracer):
     """Run the work list grouped by (m, n, r) shape-class; returns
     (leaf, WeightInfo) per item, in work-list order. The rank joins the
     class key so per-weight overrides (``CURConfig.ranks``) batch
     correctly — same-shape weights at different planned ranks land in
     different vmapped calls.
+
+    A class of more than ``CLASS_MAX_ELEMENTS`` runs as that many equal
+    chunks, one call each (the span's ``chunks`` attr).
 
     ``WeightInfo.seconds`` is the class's time over its weights; on a
     process's first call of a class that includes the compile (the
@@ -304,26 +392,34 @@ def _compress_batched(work: List[_WorkItem], cur_cfg: CURConfig, tracer):
     results: List[Optional[Tuple[dict, WeightInfo]]] = [None] * len(work)
     for (m, n, r), idxs in classes.items():
         t0 = time.perf_counter()
+        n_experts = sum(work[i].expert is not None for i in idxs)
+        n_chunks = -(-len(idxs) * m * n // CLASS_MAX_ELEMENTS)
+        size = -(-len(idxs) // n_chunks)
+        chunks = [idxs[c:c + size] for c in range(0, len(idxs), size)]
         with tracer.span("compress.class", m=m, n=n, r=r,
-                         k=len(idxs)) as span:
+                         k=len(idxs), experts=n_experts,
+                         chunks=len(chunks)) as span:
             if tracer.enabled:
                 programs0 = compiles.jit_programs()[0]
-            with tracer.span("compress.class.stack"):
-                Ws = jnp.stack([work[i].W for i in idxs])
-                acts = jnp.stack([
-                    jnp.asarray(work[i].act, jnp.float32)
-                    if work[i].act is not None
-                    else jnp.zeros((m,), jnp.float32) for i in idxs])
-                keys = jnp.stack([work[i].key for i in idxs])
-            out = _compress_class_batched(
-                Ws, acts, keys, r=r, selection=cur_cfg.selection,
-                svd=cur_cfg.svd)
-            # ONE host transfer per class for the scalar/index fields;
-            # the big factors stay device-resident in the returned leaves
-            with tracer.span("compress.class.wait"):
-                ps, qs, errs, frows, bounds = jax.device_get(
-                    (out["p"], out["q"], out["err"], out["frow"],
-                     out["bound"]))
+            done = []
+            for chunk in chunks:
+                with tracer.span("compress.class.stack"):
+                    Ws = jnp.stack([work[i].W for i in chunk])
+                    acts = jnp.stack([
+                        jnp.asarray(work[i].act, jnp.float32)
+                        if work[i].act is not None
+                        else jnp.zeros((m,), jnp.float32) for i in chunk])
+                    keys = jnp.stack([work[i].key for i in chunk])
+                out = _compress_class_batched(
+                    Ws, acts, keys, r=r, selection=cur_cfg.selection,
+                    svd=cur_cfg.svd)
+                del Ws
+                # ONE host transfer per call for the scalar/index fields;
+                # the big factors stay device-resident in the leaves
+                with tracer.span("compress.class.wait"):
+                    done.append((chunk, out, jax.device_get(
+                        (out["p"], out["q"], out["err"], out["frow"],
+                         out["bound"]))))
             if tracer.enabled:
                 span.set(programs=compiles.jit_programs()[0] - programs0)
         dt = (time.perf_counter() - t0) / len(idxs)
@@ -338,23 +434,25 @@ def _compress_batched(work: List[_WorkItem], cur_cfg: CURConfig, tracer):
             shape=f"{m}x{n}r{r}").observe(dt)
         before, unfolded, folded, deployed = _param_counts(
             m, n, r, cur_cfg.fold_u)
-        for k, i in enumerate(idxs):
-            it = work[i]
-            leaf = {
-                "C": out["C"][k].astype(it.W.dtype),
-                "U0": out["U"][k],
-                "dU": jnp.zeros_like(out["U"][k]),
-                "R": out["R"][k].astype(it.W.dtype),
-            }
-            info = WeightInfo(
-                layer=it.layer, name=it.name, shape=(m, n), rank=r,
-                rows=ps[k], cols=qs[k],
-                fro_err=float(errs[k]), fro_w=float(frows[k]),
-                bound=float(bounds[k]), seconds=dt,
-                params_before=before, params_after=deployed,
-                params_after_unfolded=unfolded, params_after_folded=folded,
-                bound_on=_bound_on(cur_cfg.selection))
-            results[i] = (leaf, info)
+        for chunk, out, (ps, qs, errs, frows, bounds) in done:
+            for k, i in enumerate(chunk):
+                it = work[i]
+                leaf = {
+                    "C": out["C"][k].astype(it.W.dtype),
+                    "U0": out["U"][k],
+                    "dU": jnp.zeros_like(out["U"][k]),
+                    "R": out["R"][k].astype(it.W.dtype),
+                }
+                info = WeightInfo(
+                    layer=it.layer, name=it.name, shape=(m, n), rank=r,
+                    rows=ps[k], cols=qs[k],
+                    fro_err=float(errs[k]), fro_w=float(frows[k]),
+                    bound=float(bounds[k]), seconds=dt,
+                    params_before=before, params_after=deployed,
+                    params_after_unfolded=unfolded,
+                    params_after_folded=folded,
+                    bound_on=_bound_on(cur_cfg.selection), expert=it.expert)
+                results[i] = (leaf, info)
     return results
 
 
@@ -371,12 +469,21 @@ def unrolled_config(cfg: ModelConfig) -> ModelConfig:
 
 
 def unroll_params(params, cfg: ModelConfig):
-    """Restructure params to match ``unrolled_config``."""
+    """Restructure params to match ``unrolled_config``. A group that
+    already holds one layer hands its leaves over as they are (each
+    block in a dict of its own, so writing a compressed leaf into the
+    result leaves ``params`` alone); a stacked group is sliced."""
     new = {k: v for k, v in params.items() if k != "groups"}
     new["groups"] = []
-    for li, spec, lp in iter_layer_params(params, cfg):
-        stacked = jax.tree.map(lambda a: a[None], lp)
-        new["groups"].append([stacked])
+    for gi, (pattern, reps) in enumerate(cfg.groups):
+        if reps == 1:
+            new["groups"].extend([dict(block)]
+                                 for block in params["groups"][gi])
+            continue
+        for r in range(reps):
+            for block in params["groups"][gi]:
+                lp = jax.tree.map(lambda a: a[r], block)
+                new["groups"].append([jax.tree.map(lambda a: a[None], lp)])
     return new
 
 
@@ -387,18 +494,12 @@ def _cur_work_list(params, cfg: ModelConfig, cur_cfg: CURConfig,
     therefore identical for the loop and batched pipelines."""
     key = jax.random.PRNGKey(cur_cfg.seed)
     work: List[_WorkItem] = []
-    for li, spec, lp in iter_layer_params(params, cfg):
-        if li not in layer_set:
+    for li, t, leaf, r in _layer_targets(params, cfg):
+        if li not in layer_set or leaf.ndim not in (3, 4):
             continue
-        for t in cfg.cur_targets:
-            if t not in lp:
-                continue
-            W = lp[t]
-            if isinstance(W, dict):              # already CUR-compressed
-                continue                         # (progressive later round)
-            if W.ndim != 2:                      # (e.g. MoE expert stacks)
-                continue
-            if cur_cfg.ranks and rank_key(li, t) not in cur_cfg.ranks:
+        experts = _held_ids(cfg) if leaf.ndim == 4 else [None]
+        for j, e in enumerate(experts):
+            if cur_cfg.ranks and rank_key(li, t, e) not in cur_cfg.ranks:
                 # a ranks map IS the complete allocation (a plan): weights
                 # it omits — e.g. too small for any profiled rank to save
                 # params — stay dense, so the executed compression matches
@@ -409,10 +510,36 @@ def _cur_work_list(params, cfg: ModelConfig, cur_cfg: CURConfig,
             if act is None and cur_cfg.selection in ("wanda_deim", "wanda"):
                 raise ValueError(
                     f"no calibration activations for layer {li} weight {t}")
+            W = leaf[r] if e is None else leaf[r, j]
+            if e is not None and act is not None:
+                act = act[j]
             work.append(_WorkItem(li, t, W, act, sub,
                                   resolve_rank(W.shape[0], W.shape[1],
-                                               li, t, cur_cfg)))
+                                               li, t, cur_cfg, e), e))
     return work
+
+
+def _stack_experts(leaves: List[dict]) -> dict:
+    """Healing-form leaves of one layer's held experts, in order -> one
+    stacked leaf; ranks that differ are zero-padded to the largest."""
+    r = max(leaf["R"].shape[0] for leaf in leaves)
+
+    def pad(a, axes):
+        if all(a.shape[i] == r for i in axes):
+            return a
+        return jnp.pad(a, [(0, r - a.shape[i]) if i in axes else (0, 0)
+                           for i in range(a.ndim)])
+    return {"C": jnp.stack([pad(x["C"], (1,)) for x in leaves]),
+            "U0": jnp.stack([pad(x["U0"], (0, 1)) for x in leaves]),
+            "dU": jnp.stack([pad(x["dU"], (0, 1)) for x in leaves]),
+            "R": jnp.stack([pad(x["R"], (0,)) for x in leaves])}
+
+
+def _stack_params(stack: dict, fold_u: bool) -> int:
+    """Parameters a stacked leaf deploys (padding included)."""
+    E, m, r = stack["C"].shape
+    n = stack["R"].shape[2]
+    return E * _param_counts(m, n, r, fold_u)[3]
 
 
 def compress_model(params, cfg: ModelConfig, cur_cfg: CURConfig,
@@ -423,7 +550,8 @@ def compress_model(params, cfg: ModelConfig, cur_cfg: CURConfig,
     ``tracer`` records the sub-steps (module docstring); the caller's
     span around the call names the stage. ``CompressInfo.seconds_fold``
     is the ``compress.fold`` span: every fold dispatched, then one wait
-    for all of them."""
+    for all of them; with expert stacks, and the fold on, it adds the
+    ``compress.experts`` span (the stacks' assembly and fold)."""
     tracer = tracer or NULL_TRACER
     t_start = time.perf_counter()
     with tracer.span("compress.distances"):
@@ -443,14 +571,20 @@ def compress_model(params, cfg: ModelConfig, cur_cfg: CURConfig,
         results = [compress_weight(it.W, it.name, it.layer, cur_cfg,
                                    it.act, it.key, rank=it.rank)
                    for it in work]
+        for it, (_, info) in zip(work, results):
+            info.expert = it.expert
     elif cur_cfg.pipeline == "batched":
         results = _compress_batched(work, cur_cfg, tracer)
     else:
         raise ValueError(cur_cfg.pipeline)
 
-    # Eq. 2 guard, deployed form
+    # Eq. 2 guard, deployed form: per weight, and per stack of experts
     kept = [(it, leaf, info) for it, (leaf, info) in zip(work, results)
-            if info.params_after < info.params_before]
+            if it.expert is None and info.params_after < info.params_before]
+    stacks: Dict[Tuple[int, str], list] = {}
+    for it, (leaf, info) in zip(work, results):
+        if it.expert is not None:
+            stacks.setdefault((it.layer, it.name), []).append((leaf, info))
     seconds_fold = 0.0
     if cur_cfg.fold_u:
         t_fold = time.perf_counter()
@@ -460,9 +594,39 @@ def compress_model(params, cfg: ModelConfig, cur_cfg: CURConfig,
                 jax.block_until_ready([leaf["CU"] for _, leaf, _ in kept])
         seconds_fold = time.perf_counter() - t_fold
     for it, leaf, info in kept:
-        block = new_params["groups"][it.layer][0]
-        block[it.name] = jax.tree.map(lambda a: a[None], leaf)
-    infos: List[WeightInfo] = [info for _, _, info in kept]
+        _set_target(new_params["groups"][it.layer][0], it.name,
+                    jax.tree.map(lambda a: a[None], leaf))
+    kept_ids = {id(info) for _, _, info in kept}
+    if stacks:
+        t_exp = time.perf_counter()
+        with tracer.span("compress.experts", k=len(stacks)):
+            done = []
+            for (li, t), items in stacks.items():
+                stack = _stack_experts([leaf for leaf, _ in items])
+                before = sum(info.params_before for _, info in items)
+                if _stack_params(stack, cur_cfg.fold_u) >= before:
+                    continue
+                if cur_cfg.fold_u:
+                    stack = {"CU": jnp.einsum(
+                        "emr,erk->emk", stack["C"].astype(jnp.float32),
+                        stack["U0"] + stack["dU"]).astype(stack["C"].dtype),
+                        "R": stack["R"]}
+                _set_target(new_params["groups"][li][0], t,
+                            jax.tree.map(lambda a: a[None], stack))
+                done.append(stack)
+                kept_ids.update(id(info) for _, info in items)
+            with tracer.span("compress.experts.wait"):
+                jax.block_until_ready(done)
+        if cur_cfg.fold_u:
+            seconds_fold += time.perf_counter() - t_exp
+        obs_metrics.counter(
+            "repro_compress_expert_weights_total",
+            "expert weights CUR-compressed (one per held expert and "
+            "target)").inc(sum(len(items) for items in stacks.values()
+                               if id(items[0][1]) in kept_ids))
+    # in work-list (network) order
+    infos: List[WeightInfo] = [info for _, info in results
+                               if id(info) in kept_ids]
 
     cinfo = CompressInfo(
         distances=distances, layers=sorted(layer_set), weights=infos,

@@ -11,6 +11,9 @@ Layout contract (DESIGN.md §4), derived per-leaf from the key path:
   - Embeddings are vocab-sharded over 'model' (the loss uses a one-hot
     contraction, so no logits all-gather); falls back to d_model-sharding
     when the vocab does not divide (e.g. mamba2's 50280).
+  - MLA: ``wk_b`` / ``wv_b`` (latent -> heads) are column-parallel;
+    ``wkv_a`` (-> the latent and the shared rope key) and the latent
+    cache replicate over 'model', since every head reads them.
   - MoE experts: expert-parallel over 'model' when E % model == 0
     (kimi 384e, jamba 16e), expert-TP over the intermediate dim otherwise
     (mixtral 8e over 16).
@@ -55,6 +58,7 @@ _STATE_KEYS = frozenset(STATE_FULL_KEYS) | frozenset(STATE_SCALE_KEYS)
 # 'data' when fsdp
 _COL_PARALLEL = frozenset((
     "wq", "wk", "wv",                     # attention projections
+    "wk_b", "wv_b",                       # MLA latent -> per-head k / v
     "w_z", "w_x", "w_B", "w_C", "w_dt",   # mamba in-projections
 ))
 # row-parallel (..., in, out) weights: shard in over 'model', out over 'data'
@@ -151,7 +155,9 @@ def _dense_core(role: str, path, leaf_shape, cfg: ModelConfig, mesh):
         return (fs, "model")
     if role in _ROW_PARALLEL:
         return ("model", fs)
-    if role == "router":
+    if role in ("router", "wkv_a"):
+        # the router's experts and MLA's latent (shared by every head)
+        # replicate over 'model'
         return (fs, None)
     if role in ("w_gate", "w_up", "w_down"):
         blk = _block_spec_at(path, cfg)
@@ -286,6 +292,9 @@ def _cache_leaf_spec(path, leaf, cfg: ModelConfig, mesh):
             if spec is not None and any(a == "model" for a in tuple(spec)):
                 return spec
         return _guard(shape, [None, dp, None, None, None], mesh)
+    if key in ("c", "kr") and nd == 4:         # MLA latent (reps, B, L, r)
+        # shared by every head: batch over data, replicated over 'model'
+        return _guard(shape, [None, dp, None, None], mesh)
     if key == "pos" and nd >= 3:               # (reps, B, L)
         return _guard(shape, [None, dp, None], mesh)
     if key == "state" and nd >= 5:             # (reps, B, nh, hp, N)

@@ -28,7 +28,6 @@ from repro import obs
 from repro.configs import ARCHS, get_config, get_smoke
 from repro.configs.base import CURConfig
 from repro.core import calibrate, compress_model
-from repro.core.compress import rank_key
 from repro.data.tokens import DataConfig, SyntheticLM
 from repro.dist.checkpoint import CheckpointManager, save_tree_template
 from repro.launch import compile_cache
@@ -184,7 +183,7 @@ def cure(args) -> dict:
             "ckpt_dir": draft_dir,
             "budget_params": args.draft_budget_params,
             "layers_compressed": dinfo.layers,
-            "ranks": {rank_key(x.layer, x.name): x.rank for x in dw},
+            "ranks": {x.key: x.rank for x in dw},
             "params_deployed": d_after,
             "realized_fraction": round(d_after / max(d_before, 1), 6),
             "model_params_saved": dinfo.params_saved,
@@ -208,7 +207,7 @@ def cure(args) -> dict:
     # only meaningful alongside the allocation that produced them
     plan_report = {
         "source": plan_source,                    # uniform | budget | file
-        "ranks": {rank_key(x.layer, x.name): x.rank for x in w},
+        "ranks": {x.key: x.rank for x in w},
         "budget": {
             "kind": plan.budget_kind if plan else "params",
             "requested": plan.budget_requested if plan else None,
@@ -246,7 +245,8 @@ def cure(args) -> dict:
                 100.0 * info.params_saved / max(cfg.param_count(), 1), 3),
         },
         "weights": [{
-            "layer": x.layer, "name": x.name, "shape": list(x.shape),
+            "key": x.key, "layer": x.layer, "name": x.name,
+            "expert": x.expert, "shape": list(x.shape),
             "rank": x.rank,
             "rel_fro_err": round(x.fro_err / max(x.fro_w, 1e-30), 6),
             "bound": None if np.isnan(x.bound) else round(x.bound, 4),
@@ -392,7 +392,7 @@ def main(argv=None):
                 default=None)
     if worst:
         print(f"  worst rel fro err: {worst['rel_fro_err']:.4f} "
-              f"(layer {worst['layer']} {worst['name']})")
+              f"({worst['key']})")
     print(f"  generated {report['generate']['tokens']} tokens via "
           f"{report['generate']['engine']} "
           f"({report['generate']['tok_per_s']:.1f} tok/s)")

@@ -120,16 +120,21 @@ def structural_cur(params, cfg: ModelConfig, cur_cfg: CURConfig):
         for pi, spec in enumerate(pattern):
             block = dict(params["groups"][gi][pi])
             for t in cfg.cur_targets:
-                if t not in block:
-                    continue
-                leaf = block[t]
+                # "shared.w_gate": the weight w_gate of block["shared"]
+                outer, _, name = t.rpartition(".")
+                holder = block
+                if outer:
+                    if outer not in block:
+                        continue
+                    holder = block[outer] = dict(block[outer])
+                leaf = holder.get(name)
                 if not hasattr(leaf, "shape"):
                     continue
                 m, n = leaf.shape[-2], leaf.shape[-1]
                 r = rank_for(m, n, cur_cfg.r_max)
                 if m * r + r * r + r * n >= m * n:
                     continue  # Eq. 2: no saving, keep dense
-                block[t] = _cur_struct(leaf, cur_cfg.r_max)
+                holder[name] = _cur_struct(leaf, cur_cfg.r_max)
             group.append(block)
         new["groups"].append(group)
     return new

@@ -15,9 +15,11 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, ATTN_LOCAL, MAMBA, MLP, MOE, ModelConfig
+from repro.configs.base import (
+    ATTN, ATTN_LOCAL, MAMBA, MLA, MLP, MOE, ModelConfig)
 from repro.models import attention as attn
 from repro.models import mamba as mb
+from repro.models import mla
 from repro.models.layers import dense_init, embed_init, norm
 from repro.models.mlp import mlp_forward
 from repro.models.moe import moe_forward
@@ -32,18 +34,21 @@ from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 # ---------------------------------------------------------------------------
 
 def _init_moe_experts(key, cfg, dtype):
-    E = cfg.n_experts
+    """The router over all n_experts and the stacks of the held ones."""
+    E = cfg.n_held_experts
     D = cfg.d_model
     F = cfg.moe_d_ff or cfg.d_ff
     k1, k2, k3, k4 = jax.random.split(key, 4)
     init = jax.vmap(lambda k, m, n: dense_init(k, m, n, dtype),
                     in_axes=(0, None, None))
     p = {
-        "router": dense_init(k1, D, E, jnp.float32),
+        "router": dense_init(k1, D, cfg.n_experts, jnp.float32),
         "w_gate": init(jax.random.split(k2, E), D, F),
         "w_up": init(jax.random.split(k3, E), D, F),
         "w_down": init(jax.random.split(k4, E), F, D),
     }
+    if cfg.moe_router_bias:
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
     if cfg.n_shared_experts:
         ks = jax.random.split(jax.random.fold_in(key, 7), 3)
         Fs = cfg.n_shared_experts * F
@@ -71,6 +76,16 @@ def init_block(key, spec, cfg: ModelConfig) -> Params:
         if cfg.qk_norm:
             p["q_norm"] = jnp.ones((hd,), dtype)
             p["k_norm"] = jnp.ones((hd,), dtype)
+    elif spec.mixer == MLA:
+        H, r = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        p["wq"] = dense_init(keys[0], D, H * (dn + dr), dtype)
+        p["wkv_a"] = dense_init(keys[1], D, r + dr, dtype)
+        p["kv_norm"] = {"scale": jnp.ones((r,), dtype)}
+        p["wk_b"] = dense_init(keys[2], r, H * dn, dtype)
+        p["wv_b"] = dense_init(keys[4], r, H * dv, dtype)
+        p["wo"] = dense_init(keys[3], H * dv, D, dtype)
     elif spec.mixer == MAMBA:
         di, N, nh = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
         Kc = cfg.ssm_conv
@@ -147,6 +162,8 @@ def block_forward(x, p, spec, cfg, positions, mesh=None):
     if spec.mixer in (ATTN, ATTN_LOCAL):
         win = cfg.window if spec.mixer == ATTN_LOCAL else 0
         a = attn.attn_forward(h, p, cfg, positions, window=win)
+    elif spec.mixer == MLA:
+        a = mla.mla_forward(h, p, cfg, positions)
     elif spec.mixer == MAMBA:
         a = mb.mamba_forward(h, p, cfg)
     else:
@@ -283,6 +300,8 @@ def _init_block_cache(spec, cfg, batch, max_len, dtype):
     if spec.mixer in (ATTN, ATTN_LOCAL):
         win = cfg.window if spec.mixer == ATTN_LOCAL else 0
         return attn.init_attn_cache(cfg, batch, max_len, win, dtype)
+    if spec.mixer == MLA:
+        return mla.init_mla_cache(cfg, batch, max_len, dtype)
     if spec.mixer == MAMBA:
         return mb.init_mamba_cache(cfg, batch, dtype)
     return {}
@@ -307,6 +326,8 @@ def _block_prefill(x, p, c, spec, cfg, positions, mesh=None):
     if spec.mixer in (ATTN, ATTN_LOCAL):
         win = cfg.window if spec.mixer == ATTN_LOCAL else 0
         a, c = attn.attn_prefill(h, p, cfg, positions, c, window=win)
+    elif spec.mixer == MLA:
+        a, c = mla.mla_prefill(h, p, cfg, positions, c)
     elif spec.mixer == MAMBA:
         a, c = mb.mamba_prefill(h, p, cfg)
     else:
@@ -324,6 +345,8 @@ def _block_decode(x, p, c, spec, cfg, pos, mesh=None):
     if spec.mixer in (ATTN, ATTN_LOCAL):
         win = cfg.window if spec.mixer == ATTN_LOCAL else 0
         a, c = attn.attn_decode(h, p, cfg, c, pos, window=win)
+    elif spec.mixer == MLA:
+        a, c = mla.mla_decode(h, p, cfg, c, pos)
     elif spec.mixer == MAMBA:
         a, c = mb.mamba_decode(h, p, cfg, c)
     else:

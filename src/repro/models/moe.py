@@ -2,9 +2,16 @@
 
 Three implementations, selected by ``cfg.moe_impl`` and mesh availability:
 
-  - ``dense``: every expert applied to every token, gated by the top-k
-    routing weights. O(T·E·D·F) — only for smoke tests AND as the oracle
-    the distributed paths are verified against.
+  - ``dense`` (also every call without a mesh, and every config that
+    holds a share of the experts, ``cfg.experts_held``): the held-experts
+    layer. It routes over all ``n_experts``, applies every held expert to
+    every token, and keeps, per token, the gated outputs of the held
+    experts among its top-k, gates normalised over all k picked as in the
+    deployment: one chip's part of an expert-parallel layer, with no
+    exchange and no dropped token. O(T·E_held·D·F) — the smoke path, the
+    oracle the distributed paths are verified against, and one chip's
+    share of a layer split over chips. Expert weights may be dense
+    ``(E, D, F)`` stacks or CUR-factorized stacks (``_expert_mm``).
 
   - ``a2a`` with E % model_axis == 0 (kimi 384e, jamba 16e): production
     expert parallelism. Tokens are sequence-sharded over the 'model' axis,
@@ -19,7 +26,11 @@ Three implementations, selected by ``cfg.moe_impl`` and mesh availability:
     F sharded over 'model'; dispatch is local (sort + capacity buffer),
     outputs are combined locally then psum-reduced over 'model'.
 
-Routing: softmax-then-top-k with renormalized gates (Mixtral convention).
+Routing: softmax-then-top-k with renormalized gates (Mixtral convention)
+by default; ``cfg.moe_scoring="sigmoid"`` is DeepSeek-V3's noaux_tc with
+one group: sigmoid scores, top-k of score + a selection-only bias
+(``router_bias``), renormalised over the top-k, scaled by
+``cfg.moe_routed_scale``.
 """
 from __future__ import annotations
 
@@ -42,13 +53,36 @@ def shard_map(f, mesh, in_specs, out_specs):
 # routing helpers
 # ---------------------------------------------------------------------------
 
-def route(xt, router, k):
+def route(xt, router, k, cfg=None, bias=None):
     """xt (T,D) -> (gates (T,k) f32, experts (T,k) i32)."""
+    if cfg is not None and cfg.moe_scoring == "sigmoid":
+        # scored in float32 at full precision, as the published router
+        logits = jnp.matmul(xt.astype(jnp.float32),
+                            router.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        pick = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, topi = jax.lax.top_k(pick, k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-20)
+        return topv * cfg.moe_routed_scale, topi
     logits = (xt @ router.astype(xt.dtype)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     topv, topi = jax.lax.top_k(probs, k)
     topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
     return topv, topi
+
+
+def route_held(xt, p, cfg):
+    """xt (T,D) -> (gates (T,E_held) f32, picked (T,E_held) bool): each
+    held expert's gate for every token (0 where it is not among the
+    token's top-k) and whether it was picked."""
+    gates, experts = route(xt, p["router"], cfg.n_experts_per_tok, cfg,
+                           p.get("router_bias"))
+    first, stop = cfg.held_experts
+    hit = experts[:, :, None] == jnp.arange(first, stop)   # (T,k,E_held)
+    return (jnp.where(hit, gates[:, :, None], 0.0).sum(axis=1),
+            hit.any(axis=1))
 
 
 def _rank_within_expert(fe):
@@ -85,7 +119,7 @@ def _expert_ffn(h, wg, wu, wd, act):
     """h (E,C,D) x weights (E,D,F)/(E,F,D) -> (E,C,D)."""
     g = act(_expert_mm(h, wg))
     u = _expert_mm(h, wu)
-    return jnp.einsum("ecf,efd->ecd", g * u, wd)
+    return _expert_mm(g * u, wd)
 
 
 # ---------------------------------------------------------------------------
@@ -93,18 +127,17 @@ def _expert_ffn(h, wg, wu, wd, act):
 # ---------------------------------------------------------------------------
 
 def moe_dense(x, p, cfg):
+    """The held-experts layer (module docstring). x (B,S,D) -> (B,S,D)."""
     B, S, D = x.shape
     T = B * S
-    k = cfg.n_experts_per_tok
     act = act_fn(cfg.mlp_act)
     xt = x.reshape(T, D)
-    gates, experts = route(xt, p["router"], k)
-    # all-experts compute, gather selected
-    g = act(jnp.einsum("td,edf->tef", xt, p["w_gate"]))
-    u = jnp.einsum("td,edf->tef", xt, p["w_up"])
-    y = jnp.einsum("tef,efd->ted", g * u, p["w_down"])      # (T,E,D)
-    sel = jnp.take_along_axis(y, experts[:, :, None], axis=1)  # (T,k,D)
-    out = (sel * gates[:, :, None].astype(sel.dtype)).sum(axis=1)
+    with jax.named_scope("moe_route"):
+        gates, _ = route_held(xt, p, cfg)
+    with jax.named_scope("moe_experts"):
+        h = jnp.broadcast_to(xt, (cfg.n_held_experts, T, D))
+        y = _expert_ffn(h, p["w_gate"], p["w_up"], p["w_down"], act)
+        out = jnp.einsum("etd,te->td", y, gates.astype(y.dtype))
     if cfg.n_shared_experts:
         out = out + mlp_forward(xt, p["shared"], cfg)
     return out.reshape(B, S, D)
@@ -118,7 +151,7 @@ def _dp_axes(mesh):
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
 
 
-def _moe_body_a2a(xs, router, wg, wu, wd, *, cfg, n):
+def _moe_body_a2a(xs, rp, wg, wu, wd, *, cfg, n):
     """Expert-parallel body. xs (B,S_loc,D); wg/wu/wd (E_loc,D,F)."""
     k = cfg.n_experts_per_tok
     E = cfg.n_experts
@@ -127,7 +160,7 @@ def _moe_body_a2a(xs, router, wg, wu, wd, *, cfg, n):
     B, S, D = xs.shape
     T = B * S
     xt = xs.reshape(T, D)
-    gates, experts = route(xt, router, k)
+    gates, experts = route(xt, rp["router"], k, cfg, rp.get("router_bias"))
     A = T * k
     fe = experts.reshape(-1)
     fg = gates.reshape(-1)
@@ -156,7 +189,7 @@ def _moe_body_a2a(xs, router, wg, wu, wd, *, cfg, n):
     return out.reshape(B, S, D)
 
 
-def _moe_body_tp(xs, router, wg, wu, wd, *, cfg):
+def _moe_body_tp(xs, rp, wg, wu, wd, *, cfg):
     """Expert-TP body (E < model axis). xs (B,S,D) replicated over 'model';
     wg/wu (E,D,F_loc), wd (E,F_loc,D). Output psum over 'model'."""
     k = cfg.n_experts_per_tok
@@ -165,7 +198,7 @@ def _moe_body_tp(xs, router, wg, wu, wd, *, cfg):
     B, S, D = xs.shape
     T = B * S
     xt = xs.reshape(T, D)
-    gates, experts = route(xt, router, k)
+    gates, experts = route(xt, rp["router"], k, cfg, rp.get("router_bias"))
     A = T * k
     fe = experts.reshape(-1)
     fg = gates.reshape(-1)
@@ -186,7 +219,7 @@ def _moe_body_tp(xs, router, wg, wu, wd, *, cfg):
 
 def moe_forward(x, p, cfg, mesh=None):
     """Dispatch on impl + mesh. x (B,S,D) -> (B,S,D)."""
-    if cfg.moe_impl == "dense" or mesh is None:
+    if cfg.moe_impl == "dense" or mesh is None or cfg.experts_held:
         return moe_dense(x, p, cfg)
     n = mesh.shape["model"]
     dp = _dp_axes(mesh)
@@ -211,7 +244,7 @@ def moe_forward(x, p, cfg, mesh=None):
         fn = shard_map(
             body, mesh,
             in_specs=(x_spec,                        # tokens 256-way split
-                      P(None, None),                 # router replicated
+                      P(),                           # router replicated
                       P("model", None, None),        # experts EP-sharded
                       P("model", None, None),
                       P("model", None, None)),
@@ -221,13 +254,14 @@ def moe_forward(x, p, cfg, mesh=None):
         fn = shard_map(
             body, mesh,
             in_specs=(P(b_ax, None, None),           # x replicated on model
-                      P(None, None),
+                      P(),                           # router replicated
                       P(None, None, "model"),        # F sharded (TP)
                       P(None, None, "model"),
                       P(None, "model", None),
                       ),
             out_specs=P(b_ax, None, None))
-    out = fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
+    rp = {k: p[k] for k in ("router", "router_bias") if k in p}
+    out = fn(x, rp, p["w_gate"], p["w_up"], p["w_down"])
     if cfg.n_shared_experts:
         out = out + mlp_forward(x, p["shared"], cfg)
     return out

@@ -96,10 +96,11 @@ class WeightCurve:
     bound_on: str
     fro_w: float                 # ||W||_F
     func_fro_w: float            # ||diag(sqrt(act_sq)) W||_F
+    expert: Optional[int] = None  # the router's index, for an expert
 
     @property
     def key(self) -> str:
-        return rank_key(self.layer, self.name)
+        return rank_key(self.layer, self.name, self.expert)
 
 
 @dataclasses.dataclass
@@ -246,7 +247,7 @@ def profile_sensitivity(params, cfg: ModelConfig, cur_cfg: CURConfig,
                 func_err=np.asarray(ferrs[k], np.float64),
                 bound=np.asarray(bounds[k], np.float64),
                 bound_on=bound_on, fro_w=float(frows[k]),
-                func_fro_w=float(fws[k]))
+                func_fro_w=float(fws[k]), expert=it.expert)
 
     return SensitivityProfile(
         curves=[c for c in curves if c is not None],

@@ -53,11 +53,11 @@ def test_full_config_block_structure(arch):
 def test_shape_applicability_matrix():
     cells = [(a, s) for a in ARCHS for s in SHAPES
              if shape_applicable(a, SHAPES[s])]
-    # 10 archs x 3 universal shapes + 4 sub-quadratic x long_500k
-    assert len(cells) == 34
+    # 11 archs x 3 universal shapes + 4 sub-quadratic x long_500k
+    assert len(cells) == 37
     skips = [(a, "long_500k") for a in ARCHS
              if not shape_applicable(a, SHAPES["long_500k"])]
-    assert len(skips) == 6
+    assert len(skips) == 7
 
 
 def test_scan_vs_unrolled_equivalence():
